@@ -455,6 +455,9 @@ def main() -> None:
                          "regresses >20%%, or any serving ttft_p99_s "
                          "metric rises >30%%, vs its committed value")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     unknown = sorted(set(args.tables) - set(KNOWN_TABLES))
     if unknown:

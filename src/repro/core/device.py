@@ -70,6 +70,7 @@ class DeviceGroup:
         # slices/versions of it get cached.
         self._dead_buffers: list = []
         self._tracked_ids: set = set()
+        self._placed_args: dict = {}  # id(arg) -> (arg, copy on this device)
         self.n_transfers = 0  # device_put calls for kernel inputs
         self.n_cache_hits = 0
 
@@ -287,8 +288,28 @@ class DeviceGroup:
             for i, b in enumerate(program._ins)
         ]
         # offset passed as a traced scalar: no recompile per package.
-        res = fn(jnp_int32(offset_wi), *ins, *program._args)
+        res = fn(jnp_int32(offset_wi), *ins, *map(self._placed, program._args))
         return res
+
+    def _placed(self, arg):
+        """``arg`` with every device array on this group's device.  A jit
+        call cannot mix arrays committed to different devices, and an
+        uncommitted one would be copied on every call, so an argument that
+        lives elsewhere (weights built on device 0 for a member on device
+        3) is copied here once and the copy reused while ``arg`` lives."""
+        leaves = jax.tree_util.tree_leaves(arg)
+        if all(not isinstance(x, jax.Array) or x.devices() == {self.device}
+               for x in leaves):
+            return arg
+        with self._xfer_lock:
+            hit = self._placed_args.get(id(arg))
+        if hit is not None and hit[0] is arg:
+            return hit[1]
+        placed = jax.device_put(arg, self.device)
+        with self._xfer_lock:
+            # Holding ``arg`` keeps its id from being reused by another.
+            self._placed_args[id(arg)] = (arg, placed)
+        return placed
 
     def simulate_service_time(self, size_wi: int, elapsed: float,
                               cost_units: Optional[float] = None) -> None:

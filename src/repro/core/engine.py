@@ -88,7 +88,12 @@ class EngineCL:
         """DeviceMask, DeviceGroup(s), or a Program."""
         for w in what:
             if isinstance(w, DeviceMask):
-                self._groups.extend(discover(w))
+                found = discover(w)
+                if not found:
+                    # Running elsewhere (the default discovery's CPU) would
+                    # hide that the asked-for device is missing.
+                    raise RuntimeError(f"no device matches {w}")
+                self._groups.extend(found)
             elif isinstance(w, DeviceGroup):
                 self._groups.append(w)
             elif isinstance(w, Program):

@@ -4,5 +4,4 @@ from repro.distributed.sharding import (  # noqa: F401
     maybe_axis,
     set_current_mesh,
     shard,
-    shard_map,
 )

@@ -33,21 +33,6 @@ def batch_axes(mesh: Optional[Mesh] = None):
     return axes if axes else None
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable shard_map.
-
-    ``jax.shard_map`` (with ``check_vma``) only exists on newer jax; older
-    releases ship ``jax.experimental.shard_map.shard_map`` with the same
-    knob named ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def _normalize(axes):
     """Canonical pspec entry: 1-tuples become the bare axis name, so
     PartitionSpec equality matches hand-written specs."""

@@ -46,9 +46,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(reachable)
     def _compute():
-        q = q_ref[0, :, 0, :]  # (bq, hd)
-        k = k_ref[0, :, 0, :]  # (bk, hd)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0]  # (bq, hd)
+        k = k_ref[0]  # (bk, hd)
+        v = v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)
@@ -60,21 +60,21 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         if window > 0:
             mask &= kpos > qpos - window
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
+        acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -142,21 +142,25 @@ def _flash_fwd_impl(q, k, v, causal, window, q_offset, block_q, block_k, interpr
         _kernel, causal=causal, window=window, q_offset=q_offset,
         bq=bq, bk=bk, nk=nk, sk=sk, scale=hd ** -0.5,
     )
+    # Heads ride the lane axis ((B, S, H*hd), a free reshape): a head's
+    # (rows, hd) tile is then a block the TPU tiles, where a size-1 head
+    # axis in the second-minor place is not.
     out = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda bi, hi, qi, ki, n_rep=n_rep: (bi, ki, hi // n_rep, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda bi, hi, qi, ki, n_rep=n_rep: (bi, ki, hi // n_rep, 0)),
+            pl.BlockSpec((1, bq, hd), lambda bi, hi, qi, ki: (bi, qi, hi)),
+            pl.BlockSpec((1, bk, hd), lambda bi, hi, qi, ki, n_rep=n_rep: (bi, ki, hi // n_rep)),
+            pl.BlockSpec((1, bk, hd), lambda bi, hi, qi, ki, n_rep=n_rep: (bi, ki, hi // n_rep)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, h, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, hd), lambda bi, hi, qi, ki: (bi, qi, hi)),
+        out_shape=jax.ShapeDtypeStruct((b, sq_p, h * hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
-    return out[:, :sq]
+    )(q.reshape(b, sq_p, h * hd), k.reshape(b, sk_p, kv * hd),
+      v.reshape(b, sk_p, kv * hd))
+    return out.reshape(b, sq_p, h, hd)[:, :sq]

@@ -99,8 +99,8 @@ def _kernel(nt_ref, pos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
     def _compute():
         q = q_ref[0, 0]  # (rows, hd), rows = sq*n_rep
         rows = q.shape[0]
-        k = k_ref[0, :, 0, :].astype(q.dtype)  # (bk, hd) — cache_dtype cast
-        v = v_ref[0, :, 0, :].astype(q.dtype)
+        k = k_ref[0].astype(q.dtype)  # (bk, hd) — cache_dtype cast
+        v = v_ref[0].astype(q.dtype)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (rows, bk)
@@ -109,26 +109,44 @@ def _kernel(nt_ref, pos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
         # at its own depth).  sq == 1 collapses to a uniform row mask.
         j = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // n_rep
         rowpos = pos_ref[bi] + j  # (rows, 1)
-        valid = _mask(kpos_ref[0, :][None, :], rowpos, window)  # (rows, bk)
+        valid = _mask(kpos_ref[0, 0], rowpos, window)  # (rows, bk)
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_scr[...]  # (rows, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         # Mask p explicitly (not via exp underflow): an all-masked tile has
         # m_new == NEG_INF and exp(s - m_new) == 1, which must not count.
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
+        acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)  # l == 0: no valid keys -> 0
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _scratch(rows: int, hd: int) -> list:
+    # Online-softmax state per query row; 2-D so Mosaic lays it out as
+    # (sublane, lane) tiles.
+    return [pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, hd), jnp.float32)]
+
+
+def _lane_heads(x):
+    """(N, S, KV, hd) -> (N, S, KV*hd): a free reshape that puts the heads
+    on the lane axis, so one head's tile is a (rows, hd) block at lane
+    offset ``head*hd`` — a block the TPU tiles (the minor two block dims
+    must be multiples of (8, 128) or whole), where a size-1 head axis in
+    the second-minor place is not."""
+    n, s, kv, hd = x.shape
+    return x.reshape(n, s, kv * hd)
 
 
 def _pad_cache(k, v, kpos, bk):
@@ -169,26 +187,27 @@ def flash_decode(q, k, v, kpos, pos, *, window: int = 0, block_k: int = 128,
     qg = (q.reshape(b, sq, kv, n_rep, hd)
           .transpose(0, 2, 1, 3, 4).reshape(b, kv, rows, hd))
 
-    def kv_idx(bi, gi, ki, nt, pos):
+    def tile(ki, nt, bi):
         # Clamp beyond the slot's needed tiles: same block as the previous
         # grid step -> the TPU pipeline elides the copy (ragged fetch skip).
-        return (bi, jnp.minimum(ki, nt[bi] - 1), gi, 0)
+        return jnp.minimum(ki, nt[bi] - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, kv, nk),
         in_specs=[
             pl.BlockSpec((1, 1, rows, hd), lambda bi, gi, ki, nt, pos: (bi, gi, 0, 0)),
-            pl.BlockSpec((1, bk, 1, hd), kv_idx),
-            pl.BlockSpec((1, bk, 1, hd), kv_idx),
-            pl.BlockSpec((1, bk), lambda bi, gi, ki, nt, pos: (bi, jnp.minimum(ki, nt[bi] - 1))),
+            pl.BlockSpec((1, bk, hd),
+                         lambda bi, gi, ki, nt, pos: (bi, tile(ki, nt, bi), gi)),
+            pl.BlockSpec((1, bk, hd),
+                         lambda bi, gi, ki, nt, pos: (bi, tile(ki, nt, bi), gi)),
+            # kpos as (B, nk, 1, bk): each tile's positions are a whole
+            # (1, bk) minor block, so any bk tiles.
+            pl.BlockSpec((1, 1, 1, bk),
+                         lambda bi, gi, ki, nt, pos: (bi, tile(ki, nt, bi), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, hd), lambda bi, gi, ki, nt, pos: (bi, gi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows,), jnp.float32),
-            pltpu.VMEM((rows,), jnp.float32),
-            pltpu.VMEM((rows, hd), jnp.float32),
-        ],
+        scratch_shapes=_scratch(rows, hd),
     )
     kernel = functools.partial(_kernel, window=window, nk=nk, scale=hd ** -0.5,
                                n_rep=n_rep)
@@ -197,7 +216,7 @@ def flash_decode(q, k, v, kpos, pos, *, window: int = 0, block_k: int = 128,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, rows, hd), q.dtype),
         interpret=interpret,
-    )(nt, pos, qg, k, v, kpos)
+    )(nt, pos, qg, _lane_heads(k), _lane_heads(v), kpos.reshape(b, nk, 1, bk))
     return (out.reshape(b, kv, sq, n_rep, hd)
             .transpose(0, 2, 1, 3, 4).reshape(b, sq, h, hd))
 
@@ -252,10 +271,10 @@ def flash_decode_paged(q, k, v, kpos, tables, pos, *, window: int = 0,
     qg = (q.reshape(b, sq, kv, n_rep, hd)
           .transpose(0, 2, 1, 3, 4).reshape(b, kv, rows, hd))
 
-    def kv_idx(bi, gi, ki, nt, pos, tbl):
+    def block(ki, nt, tbl, bi):
         # Clamp to the slot's needed tiles FIRST (contiguous kernel's ragged
         # fetch skip), then resolve the logical tile to its physical block.
-        return (tbl[bi, jnp.minimum(ki, nt[bi] - 1)], 0, gi, 0)
+        return tbl[bi, jnp.minimum(ki, nt[bi] - 1)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -263,19 +282,16 @@ def flash_decode_paged(q, k, v, kpos, tables, pos, *, window: int = 0,
         in_specs=[
             pl.BlockSpec((1, 1, rows, hd),
                          lambda bi, gi, ki, nt, pos, tbl: (bi, gi, 0, 0)),
-            pl.BlockSpec((1, bl, 1, hd), kv_idx),
-            pl.BlockSpec((1, bl, 1, hd), kv_idx),
-            pl.BlockSpec((1, bl),
-                         lambda bi, gi, ki, nt, pos, tbl:
-                         (tbl[bi, jnp.minimum(ki, nt[bi] - 1)], 0)),
+            pl.BlockSpec((1, bl, hd), lambda bi, gi, ki, nt, pos, tbl:
+                         (block(ki, nt, tbl, bi), 0, gi)),
+            pl.BlockSpec((1, bl, hd), lambda bi, gi, ki, nt, pos, tbl:
+                         (block(ki, nt, tbl, bi), 0, gi)),
+            pl.BlockSpec((1, 1, 1, bl), lambda bi, gi, ki, nt, pos, tbl:
+                         (block(ki, nt, tbl, bi), 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, hd),
                                lambda bi, gi, ki, nt, pos, tbl: (bi, gi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows,), jnp.float32),
-            pltpu.VMEM((rows,), jnp.float32),
-            pltpu.VMEM((rows, hd), jnp.float32),
-        ],
+        scratch_shapes=_scratch(rows, hd),
     )
     kernel = functools.partial(_paged_kernel, window=window, nk=nmax,
                                scale=hd ** -0.5, n_rep=n_rep)
@@ -284,7 +300,8 @@ def flash_decode_paged(q, k, v, kpos, tables, pos, *, window: int = 0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, rows, hd), q.dtype),
         interpret=interpret,
-    )(nt, pos, tables, qg, k, v, kpos)
+    )(nt, pos, tables, qg, _lane_heads(k), _lane_heads(v),
+      kpos.reshape(kpos.shape[0], 1, 1, bl))
     return (out.reshape(b, kv, sq, n_rep, hd)
             .transpose(0, 2, 1, 3, 4).reshape(b, sq, h, hd))
 

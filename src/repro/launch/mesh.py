@@ -7,18 +7,26 @@ must set XLA_FLAGS before any jax initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the models place activations with sharding constraints and
+    # leave the rest to the partitioner (jax.make_mesh defaults to Explicit).
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = one v5e pod (256 chips); 2x16x16 = two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests, elastic re-meshing)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return _mesh(shape, axes)
 
 
 def model_par(mesh) -> int:

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, reduced
 from repro.core import DeviceGroup, Dynamic, EngineCL, HGuided, Program, Static
 from repro.core.trace import Tracer, set_tracer, tracer
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.specs import make_batch
 from repro.models import get_model
 from repro.models.params import materialize
@@ -41,24 +42,17 @@ def _schedulers():
     return {"static": Static(), "dynamic": Dynamic(8), "hguided": HGuided()}
 
 
-def _groups(coexec: bool):
-    if not coexec:
-        return [DeviceGroup("serve:0")]
-    return [
-        DeviceGroup("pod-a", power=2.0, sim_time_per_wi=0.0),
-        DeviceGroup("pod-b", power=1.0, sim_time_per_wi=0.0),
-    ]
-
-
 def _serve_groups(args):
-    """Device groups for server mode: ``--groups N`` (simulated
-    heterogeneous pods, first twice the power of the rest) or the legacy
-    ``--coexec`` pair; one group otherwise."""
+    """Device groups for server mode: ``--groups N`` (first twice the power
+    of the rest) or the legacy ``--coexec`` pair; one group otherwise.
+    Member i runs on device i (modulo the devices there are), so on a
+    four-chip host ``--groups 4`` puts one member on each chip."""
     n = max(args.groups, 2 if args.coexec else 1)
     if n == 1:
         return [DeviceGroup("serve:0")]
+    devices = jax.devices()
     return [
-        DeviceGroup(f"pod-{chr(ord('a') + i)}",
+        DeviceGroup(f"pod-{chr(ord('a') + i)}", [devices[i % len(devices)]],
                     power=(2.0 if i == 0 else 1.0), sim_time_per_wi=0.0)
         for i in range(n)
     ]
@@ -75,7 +69,8 @@ def run_coexec(cfg, api, params, batch, args) -> np.ndarray:
     extra = {k: v for k, v in batch.items() if k != "tokens"}
     generate = make_generate(cfg, api, jit=False)
 
-    def kern(offset, tokens, *extras):
+    def kern(offset, tokens, *rest):
+        *extras, params = rest
         b = {"tokens": tokens, **dict(zip(extra.keys(), extras))}
         return generate(params, b, args.gen)
 
@@ -85,11 +80,12 @@ def run_coexec(cfg, api, params, batch, args) -> np.ndarray:
         .in_(np.asarray(batch["tokens"]))
         .out(out)
         .kernel(kern, "generate")
+        .arg(params)
         .work_items(args.requests, 1)
     )
     for e in extra.values():
         prog.in_(np.asarray(e))
-    eng = EngineCL().use(*_groups(True)).scheduler(
+    eng = EngineCL().use(*_serve_groups(args)).scheduler(
         _schedulers()[args.scheduler]).program(prog)
     eng.run()
     if eng.has_errors():
@@ -121,7 +117,7 @@ def _make_draft(cfg, params, args):
         dcfg = dataclasses.replace(dcfg, kernel_impl=args.kernel)
     dapi = get_model(dcfg)
     dparams = materialize(dapi.param_spec(dcfg, 1),
-                          jax.random.PRNGKey(args.seed + 3), jnp.float32)
+                          jax.random.PRNGKey(args.seed + 3), dcfg.compute_dtype)
     return DraftSpec(dcfg, dparams, k=args.draft_k,
                      auto_bypass=args.spec_gate)
 
@@ -205,7 +201,7 @@ def run_server(cfg, api, params, args) -> None:
                 # Wait for the *final* state before reading `rejected`: a
                 # request may pass submit-time admission and still be
                 # rejected later, at boarding time, once queue wait has
-                # eaten its budget.
+                # eaten its budget.  A failed request raises here.
                 h.wait(timeout=600)
                 results.append(None if h.rejected else h.result(timeout=600))
             wall = time.perf_counter() - t0
@@ -258,14 +254,18 @@ def run_server(cfg, api, params, args) -> None:
             f"{s['deferred']} boardings deferred"
         )
     if args.verify:
+        if s["rejected"]:
+            # A rejected request has no output to check: a run that served
+            # nothing must not pass verification.
+            raise SystemExit(f"verify: {s['rejected']} of {args.requests} "
+                             "requests were rejected")
         generate = make_generate(cfg, api)
         for p, r in zip(prompts, results):
-            if r is None:
-                continue
             want = np.asarray(generate(params, {"tokens": jnp.asarray(p[None])},
                                        args.gen))[0]
-            assert np.array_equal(r, want), (r, want)
-        print(f"verify: {sum(r is not None for r in results)} results "
+            if not np.array_equal(r, want):
+                raise SystemExit(f"verify: served {r} != one-shot {want}")
+        print(f"verify: {len(results)} results "
               "bit-identical to one-shot generate")
 
 
@@ -294,18 +294,20 @@ def main() -> None:
                          "+ prefix cache; with --groups N each group owns "
                          "its own pool and prefix-cache namespace)")
     ap.add_argument("--groups", type=int, default=1,
-                    help="server mode: co-execute across N simulated device "
-                         "groups, one batch (and, under --paged, one KV "
-                         "block pool) per group; wave placement and slot "
-                         "migration follow --scheduler")
+                    help="server mode: co-execute across N device groups, "
+                         "group i on device i, one batch (and, under "
+                         "--paged, one KV block pool) per group; wave "
+                         "placement and slot migration follow --scheduler")
     ap.add_argument("--drain-after", type=int, default=0,
                     help="server mode with --groups >1: after this many "
                          "submissions, drain the last group — its decode "
                          "slots migrate to the surviving groups at segment "
                          "boundaries (elastic scale-down; --verify still "
                          "holds)")
-    ap.add_argument("--block-len", type=int, default=4,
-                    help="tokens per KV block in --paged mode")
+    ap.add_argument("--block-len", type=int, default=16,
+                    help="tokens per KV block in --paged mode (a multiple "
+                         "of 8 under --kernel pallas: the chip tiles KV "
+                         "blocks in 8-row units)")
     ap.add_argument("--chunk-len", type=int, default=0,
                     help="chunked prefill (server mode): advance each "
                          "prompt this many tokens per decode segment "
@@ -359,6 +361,7 @@ def main() -> None:
                          "ragged flash-decode — on CPU; --verify still "
                          "holds: the kernel path is bit-identical per row)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:
@@ -376,7 +379,7 @@ def main() -> None:
         cfg = dataclasses.replace(cfg, decode_block=args.block_len)
     api = get_model(cfg)
     params = materialize(api.param_spec(cfg, 1), jax.random.PRNGKey(args.seed),
-                         jnp.float32)
+                         cfg.compute_dtype)
 
     if args.trace_out:
         set_tracer(Tracer(capacity=1 << 17, enabled=True))

@@ -21,7 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import shard, shard_map
+from repro.distributed import shard
 from repro.models import layers as L
 from repro.models.params import Spec
 
@@ -45,11 +45,15 @@ def attn_spec(cfg, par: int) -> dict:
     qa = "model" if sc in ("heads", "qheads") else None
     kva = "model" if sc == "heads" else None
     hda = "model" if sc == "hd" else None
+    # Init scales name their fan-in (d in, H*hd out): the default takes the
+    # second-minor dim, which for these head-split shapes is a head count,
+    # and draws weights so large that attention saturates and the random
+    # model turns chaotic (any rounding flips its outputs).
     spec = {
-        "wq": Spec((d, H, hd), (None, qa, hda)),
-        "wk": Spec((d, KV, hd), (None, kva, hda)),
-        "wv": Spec((d, KV, hd), (None, kva, hda)),
-        "wo": Spec((H, hd, d), (qa, hda, None)),
+        "wq": Spec((d, H, hd), (None, qa, hda), scale=d ** -0.5),
+        "wk": Spec((d, KV, hd), (None, kva, hda), scale=d ** -0.5),
+        "wv": Spec((d, KV, hd), (None, kva, hda), scale=d ** -0.5),
+        "wo": Spec((H, hd, d), (qa, hda, None), scale=(H * hd) ** -0.5),
     }
     if cfg.qkv_bias:
         spec["bq"] = Spec((H, hd), (qa, hda), "zeros")
@@ -424,7 +428,7 @@ def flash_decode_attention(q, cache, pos, cfg, *, window=0):
     spec_q = P(bax, None, None, None)
     spec_kv = P(bax, "model", None, None)
     spec_pos = P(bax, "model")
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec_q, spec_kv, spec_kv, spec_pos, P(bax)),

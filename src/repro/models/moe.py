@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import shard, shard_map
+from repro.distributed import shard
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models.params import Spec
@@ -156,7 +156,7 @@ def moe_ffn_ep(h, p, cfg):
         out = jax.lax.psum(out, "model")
         return out.reshape(b_loc, h.shape[1], d)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

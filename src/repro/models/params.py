@@ -9,6 +9,7 @@ one tree makes it impossible for shapes and shardings to drift apart.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -94,7 +95,14 @@ def materialize(tree, key, dtype):
         if scale is None:
             fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
             scale = fan_in ** -0.5
-        return (jax.random.normal(k, s.shape, jnp.float32) * scale).astype(dt)
+        return _normal(k, s.shape, scale, dt)
 
     out = [mk(s, k) for s, k in zip(leaves, keys)]
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal(key, shape, scale, dtype):
+    # One program per leaf, so only the cast result is ever held: a float32
+    # draw of a whole layer stack would need twice the weights' bytes again.
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)

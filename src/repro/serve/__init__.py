@@ -52,5 +52,6 @@ from repro.serve.step import (  # noqa: F401
     make_draft_verify_step,
     make_generate,
     make_prefill_step,
+    make_scored_continuation,
     zeros_cache,
 )

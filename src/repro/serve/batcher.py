@@ -118,11 +118,19 @@ class ModelKernels:
     """Per-server kernel factory: every BatchGroup of the same geometry
     shares one kernel *object* per (kind, shape-key), so the per-group jit
     cache (``DeviceGroup.compile_kernel`` keys on kernel identity) survives
-    group dissolve/re-form without recompiling."""
+    group dissolve/re-form without recompiling.
+
+    No kernel closes over the weights: ``jit`` compiles a closed-over array
+    into the executable as a constant, which at published widths is the
+    whole model in every program.  The weights ride instead as the one
+    argument of every Program built by :meth:`program` (``params``, or
+    ``(params, draft_params)`` when speculating), so kernels receive them
+    last: ``fn(offset, *ins, weights)``."""
 
     def __init__(self, cfg, api, params,
                  draft: Optional[DraftSpec] = None) -> None:
-        self.cfg, self.api, self.params = cfg, api, params
+        self.cfg, self.api = cfg, api
+        self.weights = params if draft is None else (params, draft.params)
         # Batch-axis geometry is max_seq-independent; probe with a tiny cache.
         self.bax = cache_batch_axes(cfg, api, 8)
         self.bax_leaves = jax.tree_util.tree_leaves(self.bax)
@@ -137,6 +145,10 @@ class ModelKernels:
             self.dbax = cache_batch_axes(draft.cfg, self.dapi, 8)
             self.dbax_leaves = jax.tree_util.tree_leaves(self.dbax)
             self.dtreedef = jax.tree_util.tree_structure(self.dbax)
+
+    def program(self) -> Program:
+        """An empty Program whose argument is this factory's weights."""
+        return Program().arg(self.weights)
 
     @property
     def spec_k(self) -> int:
@@ -232,10 +244,11 @@ class ModelKernels:
         if fn is not None:
             return fn
         decode = make_decode_step(self.cfg, self.api)
-        params, treedef, bax = self.params, self.treedef, self.bax
+        treedef, bax = self.treedef, self.bax
         tu = jax.tree_util
 
-        def seg(offset, tok, pos, *leaves):
+        def seg(offset, tok, pos, *rest):
+            *leaves, params = rest
             cache = tu.tree_unflatten(treedef, leaves)
             cache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), cache, bax)
 
@@ -267,11 +280,12 @@ class ModelKernels:
         if fn is not None:
             return fn
         decode = make_decode_step(self.cfg, self.api)
-        params, treedef, bax = self.params, self.treedef, self.bax
+        treedef, bax = self.treedef, self.bax
         n_layers = self.cfg.n_layers
         tu = jax.tree_util
 
-        def seg(offset, tok, pos, table, *leaves):
+        def seg(offset, tok, pos, table, *rest):
+            *leaves, params = rest
             cache = tu.tree_unflatten(treedef, leaves)
             cache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), cache, bax)
             cache = dict(cache)
@@ -325,10 +339,11 @@ class ModelKernels:
             return fn
         decode = make_decode_step(self.cfg, self.api)
         chunk = make_chunk_step(self.cfg, self.api, bucket, chunk_len)
-        params, treedef, bax = self.params, self.treedef, self.bax
+        treedef, bax = self.treedef, self.bax
         tu = jax.tree_util
 
-        def seg(offset, tok, pos, pcur, ptoks, *leaves):
+        def seg(offset, tok, pos, pcur, ptoks, *rest):
+            *leaves, params = rest
             cache = tu.tree_unflatten(treedef, leaves)
             cache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), cache, bax)
             decoding = pcur >= bucket  # (b, 1), phase at segment entry
@@ -372,11 +387,12 @@ class ModelKernels:
             return fn
         decode = make_decode_step(self.cfg, self.api)
         chunk = make_chunk_step(self.cfg, self.api, bucket, chunk_len)
-        params, treedef, bax = self.params, self.treedef, self.bax
+        treedef, bax = self.treedef, self.bax
         n_layers = self.cfg.n_layers
         tu = jax.tree_util
 
-        def seg(offset, tok, pos, pcur, ptoks, table, *leaves):
+        def seg(offset, tok, pos, pcur, ptoks, table, *rest):
+            *leaves, params = rest
             cache = tu.tree_unflatten(treedef, leaves)
             cache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), cache, bax)
             cache = dict(cache)
@@ -422,9 +438,9 @@ class ModelKernels:
         if fn is not None:
             return fn
         prefill = make_prefill_step(self.cfg, self.api)
-        cfg, api, params, bax = self.cfg, self.api, self.params, self.bax_leaves
+        cfg, api, bax = self.cfg, self.api, self.bax_leaves
 
-        def pre(offset, tokens):
+        def pre(offset, tokens, params):
             cache = zeros_cache(cfg, api, tokens.shape[0], max_seq)
             tok, cache = prefill(params, {"tokens": tokens}, cache)
             leaves = [jnp.moveaxis(x, a, 0)
@@ -439,7 +455,8 @@ class ModelKernels:
         return make_draft_verify_step(self.cfg, self.api, self.draft.cfg,
                                       self.dapi, self.draft.k)
 
-    def _spec_scan(self, seg_len: int, step, tok, ptok, pos, tcache, dcache):
+    def _spec_scan(self, seg_len: int, step, weights, tok, ptok, pos,
+                   tcache, dcache):
         """Shared draft/verify segment body: ``seg_len`` speculative steps,
         each emitting 1..k+1 tokens, cursor-scattered into one flat
         ``(b, seg_len*(k+1))`` buffer.  Beyond each slot's final cursor the
@@ -447,7 +464,7 @@ class ModelKernels:
         positions past ``need`` in the non-spec ``toks_seg``; harvest only
         reads ``buf[:cnt]``.  Returns (buf, cnt, tok, ptok, pos, caches)."""
         k = self.draft.k
-        params, dparams = self.params, self.draft.params
+        params, dparams = weights
         b = tok.shape[0]
         buf = jnp.zeros((b, seg_len * (k + 1)), jnp.int32)
         cur = jnp.zeros((b,), jnp.int32)
@@ -471,7 +488,7 @@ class ModelKernels:
         )
         return buf, cur[:, None], tok, ptok, pos, tcache, dcache
 
-    def _plain_scan(self, seg_len: int, decode, tok, ptok, pos,
+    def _plain_scan(self, seg_len: int, decode, weights, tok, ptok, pos,
                     tcache, dcache):
         """Bypass branch of the speculative segment: ``seg_len`` plain
         decode steps on the target cache only, shaped like
@@ -483,7 +500,7 @@ class ModelKernels:
         lowers the acceptance rate, never correctness (verify is always
         against the target)."""
         k = self.draft.k
-        params = self.params
+        params, _ = weights
         b = tok.shape[0]
 
         def body(carry, _):
@@ -504,7 +521,7 @@ class ModelKernels:
         ptok2 = toks[:, seg_len - 2:seg_len - 1] if seg_len > 1 else tok
         return buf, cnt, tok2, ptok2, pos2, tcache, dcache
 
-    def _gated_scan(self, seg_len: int, step, decode, spec_on,
+    def _gated_scan(self, seg_len: int, step, decode, weights, spec_on,
                     tok, ptok, pos, tcache, dcache):
         """Segment-granular draft on/off switch: one host-written flag
         (``spec_on[0, 0]``) selects draft/verify or plain decode via
@@ -512,10 +529,10 @@ class ModelKernels:
         a rebuild or recompile."""
 
         def spec_branch(op):
-            return self._spec_scan(seg_len, step, *op)
+            return self._spec_scan(seg_len, step, weights, *op)
 
         def plain_branch(op):
-            return self._plain_scan(seg_len, decode, *op)
+            return self._plain_scan(seg_len, decode, weights, *op)
 
         return jax.lax.cond(spec_on[0, 0] > 0, spec_branch, plain_branch,
                             (tok, ptok, pos, tcache, dcache))
@@ -540,13 +557,14 @@ class ModelKernels:
         tu = jax.tree_util
 
         def seg(offset, tok, ptok, pos, *rest):
-            spec_on, leaves = rest[-1], rest[:-1]
+            *leaves, spec_on, weights = rest
             tcache = tu.tree_unflatten(treedef, leaves[:nt])
             tcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), tcache, bax)
             dcache = tu.tree_unflatten(dtreedef, leaves[nt:])
             dcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), dcache, dbax)
             buf, cnt, tok, ptok, pos, tcache, dcache = self._gated_scan(
-                seg_len, step, decode, spec_on, tok, ptok, pos, tcache, dcache
+                seg_len, step, decode, weights, spec_on, tok, ptok, pos,
+                tcache, dcache
             )
             tcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, a, 0), tcache, bax)
             dcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, a, 0), dcache, dbax)
@@ -575,7 +593,7 @@ class ModelKernels:
         tu = jax.tree_util
 
         def seg(offset, tok, ptok, pos, table, *rest):
-            spec_on, leaves = rest[-1], rest[:-1]
+            *leaves, spec_on, weights = rest
             tcache = tu.tree_unflatten(treedef, leaves[:nt])
             tcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), tcache, bax)
             tcache = dict(tcache)
@@ -585,7 +603,8 @@ class ModelKernels:
             dcache = tu.tree_unflatten(dtreedef, leaves[nt:])
             dcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), dcache, dbax)
             buf, cnt, tok, ptok, pos, tcache, dcache = self._gated_scan(
-                seg_len, step, decode, spec_on, tok, ptok, pos, tcache, dcache
+                seg_len, step, decode, weights, spec_on, tok, ptok, pos,
+                tcache, dcache
             )
             tcache = dict(tcache)
             tcache.pop("table")
@@ -605,9 +624,10 @@ class ModelKernels:
         never emitted bits)."""
         chunk = make_chunk_step(self.cfg, self.api, bucket, chunk_len)
         dchunk = make_chunk_step(self.draft.cfg, self.dapi, bucket, chunk_len)
-        params, dparams = self.params, self.draft.params
 
-        def stage(tok, pcur, ptoks, tcache, dcache, decoding):
+        def stage(weights, tok, pcur, ptoks, tcache, dcache, decoding):
+            params, dparams = weights
+
             def run(op):
                 tc, dc = op
                 ctok, pcur2, tc = chunk(params, tc, ptoks, pcur)
@@ -644,16 +664,17 @@ class ModelKernels:
         tu = jax.tree_util
 
         def seg(offset, tok, ptok, pos, pcur, ptoks, *rest):
-            spec_on, leaves = rest[-1], rest[:-1]
+            *leaves, spec_on, weights = rest
             tcache = tu.tree_unflatten(treedef, leaves[:nt])
             tcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), tcache, bax)
             dcache = tu.tree_unflatten(dtreedef, leaves[nt:])
             dcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), dcache, dbax)
             decoding = pcur >= bucket
             ctok, pcur2, tcache, dcache = stage(
-                tok, pcur, ptoks, tcache, dcache, decoding)
+                weights, tok, pcur, ptoks, tcache, dcache, decoding)
             buf, cnt, tok2, ptok2, pos2, tcache, dcache = self._gated_scan(
-                seg_len, step, decode, spec_on, tok, ptok, pos, tcache, dcache
+                seg_len, step, decode, weights, spec_on, tok, ptok, pos,
+                tcache, dcache
             )
             completed = ~decoding & (pcur2 >= bucket)
             last_ptok = ptoks[:, bucket - 1:bucket]
@@ -688,7 +709,7 @@ class ModelKernels:
         tu = jax.tree_util
 
         def seg(offset, tok, ptok, pos, pcur, ptoks, table, *rest):
-            spec_on, leaves = rest[-1], rest[:-1]
+            *leaves, spec_on, weights = rest
             tcache = tu.tree_unflatten(treedef, leaves[:nt])
             tcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), tcache, bax)
             tcache = dict(tcache)
@@ -699,9 +720,10 @@ class ModelKernels:
             dcache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), dcache, dbax)
             decoding = pcur >= bucket
             ctok, pcur2, tcache, dcache = stage(
-                tok, pcur, ptoks, tcache, dcache, decoding)
+                weights, tok, pcur, ptoks, tcache, dcache, decoding)
             buf, cnt, tok2, ptok2, pos2, tcache, dcache = self._gated_scan(
-                seg_len, step, decode, spec_on, tok, ptok, pos, tcache, dcache
+                seg_len, step, decode, weights, spec_on, tok, ptok, pos,
+                tcache, dcache
             )
             completed = ~decoding & (pcur2 >= bucket)
             last_ptok = ptoks[:, bucket - 1:bucket]
@@ -737,11 +759,11 @@ class ModelKernels:
             return fn
         prefill = make_prefill_step(self.cfg, self.api)
         dprefill = make_prefill_step(self.draft.cfg, self.dapi)
-        cfg, api, params = self.cfg, self.api, self.params
-        dcfg, dparams = self.draft.cfg, self.draft.params
+        cfg, api, dcfg = self.cfg, self.api, self.draft.cfg
         dapi, bax, dbax = self.dapi, self.bax_leaves, self.dbax_leaves
 
-        def pre(offset, tokens):
+        def pre(offset, tokens, weights):
+            params, dparams = weights
             b = tokens.shape[0]
             cache = zeros_cache(cfg, api, b, max_seq)
             tok, cache = prefill(params, {"tokens": tokens}, cache)
@@ -819,7 +841,7 @@ class BatchGroup:
             ptok = np.zeros((n_slots, 1), np.int32)
             leaves = leaves + kernels.draft_leaf_mirrors(n_slots, self.max_seq)
             toks_seg = np.zeros((n_slots, seg_len * (k + 1)), np.int32)
-            prog = Program().in_(tok).in_(ptok).in_(pos)
+            prog = kernels.program().in_(tok).in_(ptok).in_(pos)
             for b in leaves:
                 prog.in_(b)
             # spec_on rides LAST (after every donated leaf) so the donate
@@ -845,7 +867,7 @@ class BatchGroup:
             ]
             return
         toks_seg = np.zeros((n_slots, seg_len), np.int32)
-        prog = Program().in_(tok).in_(pos)
+        prog = kernels.program().in_(tok).in_(pos)
         for b in leaves:
             prog.in_(b)
         prog.out(toks_seg).out(np.zeros_like(tok)).out(np.zeros_like(pos))
@@ -883,7 +905,8 @@ class BatchGroup:
             ptok = np.zeros((n_slots, 1), np.int32)
             leaves = leaves + kernels.draft_leaf_mirrors(n_slots, self.max_seq)
             toks_seg = np.zeros((n_slots, seg_len * (k + 1)), np.int32)
-            prog = Program().in_(tok).in_(ptok).in_(pos).in_(pcur).in_(ptoks)
+            prog = (kernels.program().in_(tok).in_(ptok).in_(pos).in_(pcur)
+                    .in_(ptoks))
             for b in leaves:
                 prog.in_(b)
             self._spec_on = np.ones((n_slots, 1), np.int32)
@@ -908,7 +931,7 @@ class BatchGroup:
             self._ctok_out = 6
             return
         toks_seg = np.zeros((n_slots, seg_len), np.int32)
-        prog = Program().in_(tok).in_(pos).in_(pcur).in_(ptoks)
+        prog = kernels.program().in_(tok).in_(pos).in_(pcur).in_(ptoks)
         for b in leaves:
             prog.in_(b)
         prog.out(toks_seg).out(np.zeros_like(tok)).out(np.zeros_like(pos))
@@ -1009,7 +1032,7 @@ class BatchGroup:
         else:
             j = len(rows)
             tokens = np.stack([r.prompt for r in rows]).astype(np.int32)
-            prog = Program().in_(tokens)
+            prog = self.kernels.program().in_(tokens)
             prog.out(np.zeros((j, 1), np.int32))
             if self.spec_k:
                 prog.out(np.zeros((j, 1), np.int32))  # ptok0

@@ -353,8 +353,6 @@ class PagedBatchGroup(BatchGroup):
 
     # ----------------------------------------------------- program assembly
     def _build_segment_program(self):
-        from repro.core.program import Program
-
         kernels, n_slots, bl = self.kernels, self.n_slots, self.block_len
         n_blocks = pool_blocks(self.spec, n_slots, self.nmax)
         if self.state.pool is None:
@@ -392,7 +390,7 @@ class PagedBatchGroup(BatchGroup):
             dleaves = kernels.draft_leaf_mirrors(n_slots, self.max_seq)
             all_leaves = leaves + dleaves
             toks_seg = np.zeros((n_slots, self.seg_len * (k + 1)), np.int32)
-            prog = Program().in_(tok).in_(ptok).in_(pos).in_(self.table)
+            prog = kernels.program().in_(tok).in_(ptok).in_(pos).in_(self.table)
             for b in all_leaves:
                 prog.in_(b)
             # Speculation gate flag rides last (never donated or swapped):
@@ -417,7 +415,7 @@ class PagedBatchGroup(BatchGroup):
             self._plans = []
             return
         toks_seg = np.zeros((n_slots, self.seg_len), np.int32)
-        prog = Program().in_(tok).in_(pos).in_(self.table)
+        prog = kernels.program().in_(tok).in_(pos).in_(self.table)
         for b in leaves:
             prog.in_(b)
         prog.out(toks_seg).out(np.zeros_like(tok)).out(np.zeros_like(pos))
@@ -445,8 +443,6 @@ class PagedBatchGroup(BatchGroup):
         (invalid rows land in the sink block).  Non-spec ``[tok, pos, pcur,
         ptoks, table, *pool]``; speculative ``[tok, ptok, pos, pcur, ptoks,
         table, *pool, *draft]``."""
-        from repro.core.program import Program
-
         kernels, n_slots, seg_len = self.kernels, self.n_slots, self.seg_len
         pcur = np.full((n_slots, 1), self.bucket, np.int32)
         ptoks = np.zeros((n_slots, self.bucket), np.int32)
@@ -456,7 +452,7 @@ class PagedBatchGroup(BatchGroup):
             all_leaves = leaves + kernels.draft_leaf_mirrors(n_slots,
                                                              self.max_seq)
             toks_seg = np.zeros((n_slots, seg_len * (k + 1)), np.int32)
-            prog = (Program().in_(tok).in_(ptok).in_(pos).in_(pcur)
+            prog = (kernels.program().in_(tok).in_(ptok).in_(pos).in_(pcur)
                     .in_(ptoks).in_(self.table))
             for b in all_leaves:
                 prog.in_(b)
@@ -485,7 +481,8 @@ class PagedBatchGroup(BatchGroup):
             self._plans = []
             return
         toks_seg = np.zeros((n_slots, seg_len), np.int32)
-        prog = Program().in_(tok).in_(pos).in_(pcur).in_(ptoks).in_(self.table)
+        prog = (kernels.program().in_(tok).in_(pos).in_(pcur).in_(ptoks)
+                .in_(self.table))
         for b in leaves:
             prog.in_(b)
         prog.out(toks_seg).out(np.zeros_like(tok)).out(np.zeros_like(pos))
@@ -880,11 +877,18 @@ class PagedBatchGroup(BatchGroup):
 
     def _store_block(self, block: int, row: list, j: int) -> None:
         """Copy logical block ``j`` of one prefill slot row into physical
-        ``block`` across every pool leaf (numpy views along the seq axis)."""
+        ``block`` across every pool leaf (numpy views along the seq axis).
+        The row ends at ``max_seq``, which need not be a whole number of
+        blocks: the block's rows past it are reset to empty, as the row's own
+        empty positions are, so a reused block's stale timeline never reads
+        as valid."""
         bl = self.block_len
-        for leaf, src, sax in zip(self._pool_leaves(), row, self._seq_axes):
+        for leaf, src, sax, neg in zip(self._pool_leaves(), row,
+                                       self._seq_axes, self._neg_leaves):
             dst = np.moveaxis(leaf[block], sax, 0)
-            dst[:] = np.moveaxis(src, sax, 0)[j * bl:(j + 1) * bl]
+            part = np.moveaxis(src, sax, 0)[j * bl:(j + 1) * bl]
+            dst[:len(part)] = part
+            dst[len(part):] = -1 if neg else 0
 
     def _copy_block(self, dst_block: int, src_block: int) -> None:
         for leaf in self._pool_leaves():
@@ -1034,6 +1038,12 @@ def validate_paged(cfg, groups, scheduler, spec: PagedSpec, *,
             f"block_len ({spec.block_len}), got {cfg.decode_block}: the "
             "one-shot reference must tile its contiguous cache identically "
             "or the bit-identity contract breaks (DESIGN.md §10)"
+        )
+    if cfg.kernel_impl == "pallas" and spec.block_len % 8:
+        raise ValueError(
+            f"block_len {spec.block_len} does not tile on the chip: the "
+            "decode kernels read KV in blocks of block_len rows, which the "
+            "TPU needs in multiples of 8"
         )
 
 

@@ -170,6 +170,33 @@ def make_generate(cfg, api, *, jit: bool = True):
     return generate
 
 
+def make_scored_continuation(cfg, api):
+    """``scored(params, prompts, cont) -> logits (B, 1 + T, vocab)``: the
+    logits the serving path computes for prompt + continuation — prefill of
+    ``prompts`` (B, S), then ``T`` decode steps through the cache fed the
+    given ``cont`` (B, T) tokens (teacher forcing).  Row ``t`` scores the
+    token at position ``S + t``, so a reference forward pass over
+    ``[prompts, cont]`` gives the same rows at its last ``1 + T``
+    positions.  Jit it."""
+
+    def scored(params, prompts, cont):
+        params = cast_params_cached(params, cfg.compute_dtype)
+        b, s = prompts.shape
+        cache = zeros_cache(cfg, api, b, s + cont.shape[1])
+        logits, cache = api.prefill(params, {"tokens": prompts}, cfg, cache)
+
+        def body(cache, xs):
+            tok, pos = xs
+            lg, cache = api.decode(params, tok[:, None], pos, cfg, cache)
+            return cache, lg[:, -1]
+
+        steps = s + jnp.arange(cont.shape[1], dtype=jnp.int32)
+        _, rest = jax.lax.scan(body, cache, (cont.T, steps))
+        return jnp.concatenate([logits[:, -1:], jnp.swapaxes(rest, 0, 1)], 1)
+
+    return scored
+
+
 def make_decode_chain(cfg, api):
     """Multi-step greedy decode with device-resident handoff — the serving
     analog of the runtime's dataflow run graphs: ``n_steps`` dependent

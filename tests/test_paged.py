@@ -113,6 +113,23 @@ def test_pallas_kernel_paged_bit_identity(model):
         np.testing.assert_array_equal(got, want)
 
 
+def test_blocks_past_max_seq_stay_empty_across_reuse(model, reference):
+    """A 20-token prompt with 6 new tokens (max_seq 26) in 16-token blocks:
+    the prefill row ends inside the tail block, whose rows past it must read
+    as empty — also when the block last held another request's early
+    positions, which served requests one after another recycle."""
+    cfg, api, params = model
+    prompts = prompts_for(cfg, 41, 4, plen=20)
+    with paged_server(cfg, api, params, name="short", block_len=16,
+                      prefix=False, max_batch=1, max_new_cap=6,
+                      buckets=(20,)) as srv:
+        results = [srv.submit(p, 6).result(timeout=300) for p in prompts]
+        mem = srv.stats()["memory"]
+    for p, got in zip(prompts, results):
+        np.testing.assert_array_equal(got, reference(p, 6))
+    assert mem["allocs"] > mem["blocks_peak"], mem  # blocks were reused
+
+
 # ------------------------------------------------------------- prefix reuse
 def test_same_wave_prefix_share_and_cow_divergence(model, reference):
     """Two identical prompts in one wave with a partial tail block

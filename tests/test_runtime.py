@@ -44,6 +44,13 @@ def test_discover_mask_normalized_platforms():
     assert discover(DeviceMask.TPU, devices=[FakeDevice("cpu", 0)]) == []
 
 
+def test_use_mask_without_a_matching_device_raises():
+    """A mask that finds nothing must not fall back to every device."""
+    missing = next(m for m in (DeviceMask.TPU, DeviceMask.GPU) if not discover(m))
+    with pytest.raises(RuntimeError, match="no device matches"):
+        EngineCL().use(missing)
+
+
 # --------------------------------------------------------------- async submit
 def test_concurrent_submit_two_programs():
     eng = EngineCL().use(DeviceGroup("a"), DeviceGroup("b")).scheduler(Dynamic(6))

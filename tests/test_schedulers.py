@@ -2,11 +2,8 @@
 handed out exactly once, regardless of powers/devices/package counts)."""
 import numpy as np
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # property tests skip, unit tests still run
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import Dynamic, HGuided, Static
 from repro.core.device import DeviceGroup

@@ -1,6 +1,7 @@
 """Continuous-batching inference server: bit-identity to one-shot generate,
 multi-client concurrency, mid-stream join/exit, deadline admission, and
 device-resident segment chaining (transfer counters)."""
+import re
 import threading
 import time
 
@@ -19,11 +20,14 @@ from repro.serve import (
     Buckets,
     DeadlineAdmission,
     InferenceServer,
+    PagedSpec,
     ServiceModel,
     edf_key,
     make_generate,
     segments_for,
 )
+from repro.serve.batcher import BatchGroup, ModelKernels
+from repro.serve.paged import PagedBatchGroup, PoolState
 
 PLEN, GEN = 8, 6
 
@@ -317,3 +321,41 @@ def test_make_generate_jit_and_jitless_bit_identical(model):
     a = make_generate(cfg, api, jit=True)(params, batch, GEN)
     b = make_generate(cfg, api, jit=False)(params, batch, GEN)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _constant_sizes(hlo: str) -> list:
+    """Element counts of every constant in a lowered module's text."""
+    sizes = []
+    for line in hlo.splitlines():
+        if "stablehlo.constant" not in line:
+            continue
+        dims = re.findall(r"tensor<([^>]*)>", line)[-1].split("x")[:-1]
+        sizes.append(int(np.prod([int(d) for d in dims])))
+    return sizes
+
+
+@pytest.mark.parametrize("kind", ["decode", "paged", "chunked", "prefill"])
+def test_serving_programs_take_weights_as_arguments(model, kind):
+    """No serving kernel closes over the weights: jit compiles a closed-over
+    array into the program as a constant, which at published widths is the
+    whole model in every program.  The weights must arrive as arguments."""
+    cfg, api, params = model
+    kernels = ModelKernels(cfg, api, params)
+    if kind == "prefill":
+        fn, ins = kernels.prefill_kernel(16), [np.zeros((2, PLEN), np.int32)]
+        args = [kernels.weights]
+    else:
+        if kind == "paged":
+            grp = PagedBatchGroup(kernels, None, None, PLEN, 2, 2, 16,
+                                  PagedSpec(block_len=4), PoolState())
+        else:
+            grp = BatchGroup(kernels, None, None, PLEN, 2, 2, 16,
+                             chunk_len=2 if kind == "chunked" else 0)
+        fn, ins, args = grp.prog._kernel, grp.prog._ins, grp.prog._args
+    assert args == [kernels.weights]
+    hlo = jax.jit(fn).lower(np.int32(0), *ins, *args).as_text()
+    weights = jax.tree_util.tree_leaves(params)
+    main = next(l for l in hlo.splitlines() if "func.func public @main" in l)
+    assert main.count("%arg") >= len(weights)
+    smallest_matrix = min(w.size for w in weights if w.ndim >= 2)
+    assert max(_constant_sizes(hlo)) < smallest_matrix
