@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.program import buffer_version
+from repro.core.trace import tracer
 
 
 def jnp_int32(x: int):
@@ -73,6 +74,12 @@ class DeviceGroup:
         self._placed_args: dict = {}  # id(arg) -> (arg, copy on this device)
         self.n_transfers = 0  # device_put calls for kernel inputs
         self.n_cache_hits = 0
+        # Bytes each way, padding included: kernel inputs put on the device,
+        # kernel inputs served from the transfer cache instead, and outputs
+        # copied back to host buffers (``count_d2h``).
+        self.h2d_bytes = 0
+        self.resident_bytes = 0
+        self.d2h_bytes = 0
 
     @property
     def device(self) -> jax.Device:
@@ -158,8 +165,16 @@ class DeviceGroup:
             return {
                 "transfers": self.n_transfers,
                 "cache_hits": self.n_cache_hits,
+                "h2d_bytes": self.h2d_bytes,
+                "resident_bytes": self.resident_bytes,
+                "d2h_bytes": self.d2h_bytes,
                 "cached_entries": len(self._xfer_cache),
             }
+
+    def count_d2h(self, nbytes: int) -> None:
+        """Count ``nbytes`` of outputs copied from this group to host."""
+        with self._xfer_lock:
+            self.d2h_bytes += nbytes
 
     def _input_slice(self, program, host_buf, offset_wi: int, size_wi: int,
                      bucket: int, *, consume: bool = False):
@@ -169,7 +184,10 @@ class DeviceGroup:
         over unchanged buffers skip the host->device transfer entirely.
         ``consume`` (donated inputs): the kernel will delete the device
         array, so a cache hit is *popped* and fresh transfers are never
-        retained — each upload/handoff serves exactly one run."""
+        retained — each upload/handoff serves exactly one run.
+
+        Returns (device array, bytes put on the device, bytes served from
+        the cache)."""
         r = program.buffer_ratio(host_buf)
         lo, hi = int(r * offset_wi), int(r * (offset_wi + size_wi))
         need = int(r * bucket) - (hi - lo)
@@ -190,7 +208,8 @@ class DeviceGroup:
             if cached is not None:
                 with self._xfer_lock:
                     self.n_cache_hits += 1
-                return cached
+                    self.resident_bytes += cached.nbytes
+                return cached, 0, cached.nbytes
             if need > 0:
                 # Handoff probe: a producer run stashed this exact element
                 # range unpadded (need=0).  Padding happens device-side —
@@ -198,23 +217,25 @@ class DeviceGroup:
                 # buffer, so donating it never touches the stashed base.
                 base = self._cache_get(key[:4] + (0,))
                 if base is not None:
-                    with self._xfer_lock:
-                        self.n_cache_hits += 1
                     dev = jnp.pad(
                         base, [(0, need)] + [(0, 0)] * (base.ndim - 1)
                     )
+                    with self._xfer_lock:
+                        self.n_cache_hits += 1
+                        self.resident_bytes += dev.nbytes
                     if not consume:
                         self._cache_put(key, dev, host_buf)
-                    return dev
+                    return dev, 0, dev.nbytes
         b = host_buf[lo:hi]
         if need > 0:
             b = np.pad(np.asarray(b), [(0, need)] + [(0, 0)] * (b.ndim - 1))
         dev = jax.device_put(b, self.device)
         with self._xfer_lock:
             self.n_transfers += 1
+            self.h2d_bytes += b.nbytes
         if key is not None and not consume:
             self._cache_put(key, dev, host_buf)
-        return dev
+        return dev, b.nbytes, 0
 
     def stash_output(self, program, host_buf, offset_wi: int, size_wi: int,
                      dev_result, version: Optional[int]) -> None:
@@ -269,6 +290,7 @@ class DeviceGroup:
         patched = base.at[idx].set(vals)
         with self._xfer_lock:
             self.n_transfers += 1
+            self.h2d_bytes += idx.nbytes + vals.nbytes
             self._xfer_cache[base_key] = patched
             self._xfer_cache.move_to_end(base_key)
         return True
@@ -277,16 +299,26 @@ class DeviceGroup:
         """Run one package; returns device arrays (async, not blocked).
 
         Inputs are padded to the bucket size; callers must trim outputs to
-        ``size_wi`` (Program.write_outputs does).
+        ``size_wi`` (Program.write_outputs does).  Staging them is the
+        ``upload`` span on the group's track, with args ``bytes`` (put on
+        the device) and ``resident_bytes`` (served from the transfer
+        cache).  ``jax.device_put`` may return before its copy ends, so the
+        span is the host's share of the copy; ``bytes`` is the whole of it.
         """
         fn = self.compile_kernel(program)
         bucket = self._bucket(size_wi, program.lws)
         donated = set(program.donated_ins)
-        ins = [
-            self._input_slice(program, b, offset_wi, size_wi, bucket,
-                              consume=i in donated)
-            for i, b in enumerate(program._ins)
-        ]
+        ins, h2d, resident = [], 0, 0
+        with tracer().span("upload", track=f"group/{self.name}",
+                           kernel=program.label) as sp:
+            for i, b in enumerate(program._ins):
+                dev, up, hit = self._input_slice(program, b, offset_wi,
+                                                 size_wi, bucket,
+                                                 consume=i in donated)
+                ins.append(dev)
+                h2d += up
+                resident += hit
+            sp.set(bytes=h2d, resident_bytes=resident)
         # offset passed as a traced scalar: no recompile per package.
         res = fn(jnp_int32(offset_wi), *ins, *map(self._placed, program._args))
         return res

@@ -218,7 +218,7 @@ class Program:
         return out
 
     def write_outputs(self, offset_wi: int, size_wi: int, results: Sequence,
-                      *, bump: bool = True) -> None:
+                      *, bump: bool = True) -> int:
         """Write one package's results back to the host output buffers.
 
         ``bump=True`` (the default, tier-1 semantics) re-versions each buffer
@@ -226,19 +226,26 @@ class Program:
         version per (run, buffer) instead (``RunHandle.version_for_write``),
         so every chunk a run produces shares a single coherent version — the
         precondition for serving still-on-device output slices to dependent
-        runs from the transfer cache."""
+        runs from the transfer cache.
+
+        Returns the bytes copied to host, bucket padding included: the whole
+        result crosses before it is trimmed."""
         if not isinstance(results, (tuple, list)):
             results = (results,)
         if len(results) != len(self._outs):
             raise ValueError(
                 f"kernel returned {len(results)} outputs, program has {len(self._outs)}"
             )
+        nbytes = 0
         for b, res in zip(self._outs, results):
             r = self.buffer_ratio(b)
             lo, hi = int(r * offset_wi), int(r * (offset_wi + size_wi))
-            b[lo:hi] = np.asarray(res)[: hi - lo]  # trim bucket padding
+            host = np.asarray(res)
+            nbytes += host.nbytes
+            b[lo:hi] = host[: hi - lo]  # trim bucket padding
             if bump:
                 bump_version(b)  # output changed: stale any cached device copy
+        return nbytes
 
     def swap_buffers(self, i_in: int, i_out: int) -> None:
         """Ping-pong one (input, output) buffer pair between iterations.

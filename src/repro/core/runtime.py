@@ -86,6 +86,9 @@ class RunHandle:
         # sub-batches).  The scheduler partitions work across exactly these.
         self.targets = list(targets)
         self.introspector = introspector or Introspector()
+        # Submit time on the Introspector's clock: a run's queueing is
+        # t_submit -> introspector.t_run_start.
+        self.t_submit = time.perf_counter()
         self._lock = threading.Lock()
         self._errors: List[str] = []
         self._pending_workers = n_workers
@@ -434,10 +437,6 @@ class Runtime:
             handle = RunHandle(program, scheduler.clone(), len(targets),
                                introspector=Introspector(sink=_trace_execute),
                                deps=deps, epilogue=epilogue, targets=targets)
-            tr = tracer()
-            if tr.enabled:
-                tr.instant("submit", track="runtime", kernel=program.label,
-                           deps=len(deps))
             errs = program.validate()
             if errs:
                 handle._fail(errs)
@@ -470,17 +469,23 @@ class Runtime:
 
     def _process(self, group: DeviceGroup, handle: RunHandle) -> None:
         """Paper's Device thread body: pull → enqueue (async) → complete →
-        write, against this run's scheduler/introspector/error list."""
+        write, against this run's scheduler/introspector/error list.
+
+        On the group's track a run reads ``queue_wait`` (submit → this
+        worker picks it up) → ``dep_wait`` (predecessors) → per package
+        ``dispatch`` (with ``upload`` inside) → ``execute`` →
+        ``write_back``."""
         prog, sched = handle.program, handle.scheduler
         tr = tracer()
         track = f"group/{group.name}"
-        dep_span = tr.enabled and bool(handle.deps)
-        if dep_span:
-            tr.begin("dep_wait", track=track, kernel=prog.label,
-                     deps=len(handle.deps))
-        ok = self._await_deps(handle)
-        if dep_span:
-            tr.end("dep_wait", track=track)
+        if tr.enabled:
+            tr.complete("queue_wait", handle.t_submit, time.perf_counter(),
+                        track=track, kernel=prog.label)
+        ok = True
+        if handle.deps:
+            with tr.span("dep_wait", track=track, kernel=prog.label,
+                         deps=len(handle.deps)):
+                ok = self._await_deps(handle)
         if not ok:
             return
         handle._mark_started()
@@ -520,10 +525,10 @@ class Runtime:
                     # ONCE — host write-back below must not inflate what
                     # adaptive raters (HGuided/ThroughputRater) observe.
                     service = t_end - t_enq
-                    self._write_back(group, handle, off, size, res)
-                    if tr.enabled:
-                        tr.complete("write_back", t_end, time.perf_counter(),
-                                    track=track, offset=off, size=size)
+                    with tr.span("write_back", track=track, offset=off,
+                                 size=size) as sp:
+                        sp.set(bytes=self._write_back(group, handle, off,
+                                                      size, res))
                     handle.introspector.record(
                         PackageRecord(group.name, off, size, t_enq, t_enq, t_end)
                     )
@@ -535,21 +540,21 @@ class Runtime:
             # and must not kill the resident worker thread.
             handle.record_error(f"{group.name}: {traceback.format_exc()}")
         finally:
-            dx = group.n_transfers - xfer0
-            dh = group.n_cache_hits - hits0
-            handle.introspector.record_counters(group.name, dx, dh)
-            if tr.enabled and (dx or dh):
-                tr.instant("transfers", track=track, kernel=prog.label,
-                           transfers=dx, cache_hits=dh)
+            handle.introspector.record_counters(
+                group.name, group.n_transfers - xfer0,
+                group.n_cache_hits - hits0)
 
     def _write_back(self, group: DeviceGroup, handle: RunHandle,
-                    off: int, size: int, res) -> None:
+                    off: int, size: int, res) -> int:
         """Host write-back + device-resident handoff: the produced device
         slices are stashed in this group's transfer cache under the run's
         write version, so a dependent run reading the same elements on the
-        same group skips the host re-read and the ``jax.device_put``."""
+        same group skips the host re-read and the ``jax.device_put``.
+        Returns (and counts on ``group``) the bytes copied to host."""
         prog = handle.program
         results = res if isinstance(res, (tuple, list)) else (res,)
-        prog.write_outputs(off, size, results, bump=False)
+        nbytes = prog.write_outputs(off, size, results, bump=False)
+        group.count_d2h(nbytes)
         for b, r in zip(prog._outs, results):
             group.stash_output(prog, b, off, size, r, handle.version_for_write(b))
+        return nbytes

@@ -22,6 +22,10 @@ shared ring buffer:
   ``thread_name`` metadata event); request lifecycles ride async spans
   keyed by request sequence number, so one request's admission → chunks →
   segments → exit line up across tracks.
+- **One clock with the profiler**: a span measured in place
+  (``with tracer().span(...)``) also holds a ``jax.profiler.TraceAnnotation``
+  of its name, so a profiler trace taken meanwhile shows it on the device
+  trace's clock.  Spans computed after the fact (``complete``) do not.
 
 A module-level tracer (disabled by default) is the instrumentation target:
 ``tracer()`` returns it, ``set_tracer()`` swaps it (benchmarks install a
@@ -34,6 +38,8 @@ import json
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 
 def _thread_track() -> str:
@@ -51,23 +57,40 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tr", "_name", "_track", "_args")
+    """One span measured in place: a complete ("X") event emitted on exit,
+    and a ``jax.profiler.TraceAnnotation`` of the same name held open
+    meanwhile, so a profiler trace taken at the same time carries the span
+    on the device trace's own clock.  ``set`` adds args known only inside
+    the block (bytes copied, say)."""
+
+    __slots__ = ("_tr", "_name", "_track", "_args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, track: Optional[str],
                  args: dict) -> None:
         self._tr, self._name, self._track, self._args = tr, name, track, args
 
     def __enter__(self) -> "_Span":
-        self._tr.begin(self._name, track=self._track, **self._args)
+        self._ann = TraceAnnotation(self._name)
+        self._ann.__enter__()
+        self._t0 = self._tr.now()
         return self
 
+    def set(self, **args) -> None:
+        self._args.update(args)
+
     def __exit__(self, *exc) -> bool:
-        self._tr.end(self._name, track=self._track)
+        t1 = self._tr.now()
+        self._ann.__exit__(*exc)
+        self._tr.complete(self._name, self._t0, t1, track=self._track,
+                          **self._args)
         return False
 
 
@@ -152,7 +175,9 @@ class Tracer:
                        self._clock(), None, args)
 
     def span(self, name: str, track: Optional[str] = None, **args):
-        """``with tracer().span("phase"): ...`` — balanced begin/end."""
+        """``with tracer().span("phase") as sp: ...`` — one complete span
+        over the block, mirrored by a ``TraceAnnotation`` (see ``_Span``).
+        Disabled: the shared no-op span, no annotation created."""
         if not self._enabled:
             return _NULL_SPAN
         return _Span(self, name, track, args)

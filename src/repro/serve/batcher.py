@@ -808,17 +808,18 @@ class BatchGroup:
         self.tokens_written = 0  # KV positions actually written (memory_stats)
         self.last_run_metrics: dict = {}
         self.telemetry = None  # set by the owning InferenceServer
-        self._build_segment_program()
+        # Host mirrors of the whole cache, filled in full (about 1.3 GB for
+        # a qwen1.5-4b bucket-256 group): the batcher track's ``form_group``.
+        with tracer().span("form_group", track="batcher", bucket=bucket):
+            self._build_segment_program()
         self.seg_handle = None
         self.prev_handle = None
         self._seg_t0 = 0.0
-        self._seg_tr0 = 0.0  # tracer-clock start (0 = not traced)
         # -- in-flight prefill wave ----------------------------------------
         self.prefill_handle = None
         self.prefill_wave: List[object] = []
         self._prefill_prog: Optional[Program] = None
         self._prefill_t0 = 0.0
-        self._prefill_tr0 = 0.0  # tracer-clock start (0 = not traced)
 
     def _build_segment_program(self) -> None:
         """Contiguous layout: slot-leading mirrors, ping-pong in/out pairs
@@ -1005,8 +1006,6 @@ class BatchGroup:
         assert len(requests) <= len(self.free_slots())
         self.prefill_wave = list(requests)
         self._prefill_t0 = _now()
-        tr = tracer()
-        self._prefill_tr0 = tr.now() if tr.enabled else 0.0
         if self.chunk_len:
             # Chunked mode: there is no prefill Program — joining slots are
             # armed host-side (merge) and the segment kernel's chunk stage
@@ -1058,20 +1057,15 @@ class BatchGroup:
         start position, and full cache row into a free slot's host mirrors,
         then invalidate the mirrors (their device copies are stale).  Only
         legal between segments — an in-flight segment may slice the mirrors
-        at any moment.  Returns {"joined": n, "failed": [...], "seconds"}."""
+        at any moment.  Returns {"joined": n, "failed": [...], "seconds"}.
+        The server calls it under its lock, in a ``merge`` span: ``submit``
+        waits behind it."""
         h, wave, prog = self.prefill_handle, self.prefill_wave, self._prefill_prog
         assert h is not None and h.done()
         self.prefill_handle, self.prefill_wave, self._prefill_prog = None, [], None
         seconds = h.metrics.get("response_time") or (_now() - self._prefill_t0)
         tr = tracer()
-        if tr.enabled and self._prefill_tr0:
-            # The prefill Program's window on the batcher track (measured by
-            # the run's own introspector; merge happens at the boundary, so
-            # "now" would overstate it).
-            tr.complete("prefill_wave", self._prefill_tr0,
-                        self._prefill_tr0 + seconds, track="batcher",
-                        bucket=self.bucket, wave=len(wave))
-            self._prefill_tr0 = 0.0
+        _trace_run(tr, "prefill_wave", h, bucket=self.bucket, wave=len(wave))
         if h.has_errors():
             return {"joined": 0, "failed": list(wave), "errors": h.errors(),
                     "seconds": seconds}
@@ -1171,8 +1165,6 @@ class BatchGroup:
 
         after = [self.prev_handle] if self.prev_handle is not None else None
         self._seg_t0 = _now()
-        tr = tracer()
-        self._seg_tr0 = tr.now() if tr.enabled else 0.0
         h = self.runtime.submit(self.prog, self.scheduler,
                                 after=after, epilogue=epilogue,
                                 groups=self.target)
@@ -1182,7 +1174,9 @@ class BatchGroup:
     def harvest_segment(self) -> dict:
         """Collect a completed segment: append each active slot's new tokens
         (truncated to what the request still needs), retire finished
-        requests, and free their slots.  Returns stats for this segment."""
+        requests, and free their slots.  Returns stats for this segment.
+        The server calls it under its lock, in a ``harvest`` span:
+        ``submit`` waits behind it."""
         h = self.seg_handle
         assert h is not None and h.done()
         self.seg_handle = None
@@ -1259,12 +1253,8 @@ class BatchGroup:
                 finished.append(req)
                 self.release_slot(slot)
         self.tokens_written += emitted if self.spec_k else n_active * self.seg_len
-        if traced and self._seg_tr0:
-            tr.complete("segment", self._seg_tr0, self._seg_tr0 + seconds,
-                        track="batcher", bucket=self.bucket,
-                        n_active=n_active, finished=len(finished),
-                        chunk_tokens=chunk_tokens)
-            self._seg_tr0 = 0.0
+        _trace_run(tr, "segment", h, bucket=self.bucket, n_active=n_active,
+                   finished=len(finished), chunk_tokens=chunk_tokens)
         if self.telemetry is not None and chunk_tokens:
             self.telemetry.count("chunk_tokens", chunk_tokens)
         res = {"n_active": n_active, "finished": finished, "seconds": seconds,
@@ -1366,3 +1356,16 @@ class BatchGroup:
 
 def _now() -> float:
     return time.monotonic()
+
+
+def _trace_run(tr, name: str, h, **args) -> None:
+    """The run behind handle ``h`` as a complete span ``name`` on the
+    batcher track: from the run's start to its end (its Introspector's
+    ``t_run_start``/``t_run_end``, on the tracer's clock), with
+    ``queued_s``, the time from submit to that start.  A stand-in handle
+    (nothing ran) or a run that never started emits nothing."""
+    intro = getattr(h, "introspector", None)
+    if not tr.enabled or intro is None or not intro.t_run_start:
+        return
+    tr.complete(name, intro.t_run_start, intro.t_run_end, track="batcher",
+                queued_s=intro.t_run_start - h.t_submit, **args)

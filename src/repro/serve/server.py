@@ -345,12 +345,11 @@ class InferenceServer:
         self.telemetry = telemetry or Telemetry()
         self.admission.telemetry = self.telemetry
         # Live observability (DESIGN §15): utilization meter + decision
-        # journal + flight recorder.  Default: the continuous accounting
-        # follows the tracer (a traced run wants load curves; an untraced
-        # one must stay at one-attribute-read-per-site cost); the flight
-        # recorder is always armed — it only runs on failure paths.
-        self.obs = obs if obs is not None else EngineObs(
-            enabled=tracer().enabled)
+        # journal + flight recorder.  Default: the continuous accounting is
+        # off (one attribute read per site; pass an enabled EngineObs to
+        # meter a server, traced or not); the flight recorder is always
+        # armed — it only runs on failure paths.
+        self.obs = obs if obs is not None else EngineObs(enabled=False)
         self.obs.attach()
         self._last_counter_emit = 0.0
         # Speculation auto-bypass (opt-in via DraftSpec.auto_bypass):
@@ -721,7 +720,14 @@ class InferenceServer:
                         # instead of sleeping on a stale signal.
                         self._poke = False
                         continue
-                    self._cv.wait(timeout=timer)
+                    tr = tracer()
+                    if tr.enabled and self._quiet():
+                        # Nothing queued, nothing running: the server is
+                        # idle until the next submit.
+                        with tr.span("idle", track="batcher"):
+                            self._cv.wait(timeout=timer)
+                    else:
+                        self._cv.wait(timeout=timer)
                     self._poke = False
         except BaseException as exc:  # noqa: BLE001 — a dying batcher must
             self._crash(exc)  # resolve every handle, not strand clients
@@ -756,6 +762,17 @@ class InferenceServer:
 
     def _pending_any(self) -> bool:
         return any(self._pending.values())
+
+    def _quiet(self) -> bool:
+        """No request queued and no Program in flight (cv held)."""
+        if self._pending_any():
+            return False
+        for entry in self._groups.values():
+            for grp in (entry.values() if isinstance(entry, dict)
+                        else (entry,)):
+                if grp.seg_handle is not None or grp.prefill_handle is not None:
+                    return False
+        return True
 
     def _advance_all(self) -> Optional[float]:
         """One scheduling pass (cv held).  Returns seconds until the next
@@ -874,9 +891,16 @@ class InferenceServer:
         """Harvest a finished segment and merge a finished prefill (cv
         held); feeds the service model (segment/prefill times, per-group
         rates, spec-vs-plain mode times).  Returns False when the group
-        failed — its requests are already resolved."""
+        failed — its requests are already resolved.
+
+        The ``harvest`` and ``merge`` spans on the batcher track time
+        ``harvest_segment`` and ``merge_prefill`` (the host mirror writes
+        and the invalidation of the whole cache).  Both run under the
+        server's lock: they are what ``submit`` waits behind."""
+        tr = tracer()
         if grp.seg_handle is not None and grp.seg_handle.done():
-            res = grp.harvest_segment()
+            with tr.span("harvest", track="batcher", bucket=grp.bucket):
+                res = grp.harvest_segment()
             if "errors" in res:
                 self._fail_group(grp, res["errors"])
                 return False
@@ -897,7 +921,7 @@ class InferenceServer:
             self._stats["occupancy_sum"] += res["n_active"]
             self.telemetry.observe("segment_s", res["seconds"])
             self.telemetry.observe("occupancy", res["n_active"])
-            if self.obs.enabled or tracer().enabled:
+            if self.obs.enabled or tr.enabled:
                 self._note_segment(grp, gname, res)
             drafted = res.get("drafted", 0)
             if drafted:
@@ -914,12 +938,13 @@ class InferenceServer:
         # at any moment).
         if (grp.seg_handle is None and grp.prefill_handle is not None
                 and grp.prefill_handle.done()):
-            res = grp.merge_prefill()
+            with tr.span("merge", track="batcher", bucket=grp.bucket) as sp:
+                res = grp.merge_prefill()
+                sp.set(joined=res["joined"])
             if not self.chunk_len:  # chunked joins run no prefill Program
                 self.admission.model.observe("prefill", grp.bucket,
                                              res["seconds"])
                 self.telemetry.observe("prefill_s", res["seconds"])
-            tr = tracer()
             if res["failed"]:
                 self._postmortem(
                     "prefill_failed", bucket=grp.bucket,

@@ -266,8 +266,8 @@ def test_stats_occupancy_mean_guarded_before_any_segment(model):
 def test_traced_server_bit_identical_with_full_span_taxonomy(model):
     """Tracing on: served outputs stay bit-identical to one-shot generate,
     the trace carries every lifecycle span (request, admission, boarding,
-    segments, runtime dispatch/execute) for every request, and the
-    server's internal rolling TTFT/ITL quantiles agree with the values
+    merge, harvest, segments, runtime queue_wait/dispatch/upload/execute/
+    write_back) for every request, and the server's internal rolling TTFT/ITL quantiles agree with the values
     computed externally from the same handles."""
     cfg, api, params = model
     tr = set_tracer(Tracer(capacity=1 << 15, enabled=True))
@@ -291,8 +291,8 @@ def test_traced_server_bit_identical_with_full_span_taxonomy(model):
     evs = doc["traceEvents"]
     names = {e["name"] for e in evs}
     assert {"request", "admission", "board", "first_token", "decode_segment",
-            "segment", "submit", "dispatch", "execute",
-            "write_back"} <= names, names
+            "segment", "queue_wait", "dispatch", "upload", "execute",
+            "write_back", "merge", "harvest", "form_group"} <= names, names
     # Every request's async lifecycle is complete: one begin and one end
     # per submitted request, admission verdicts for all.
     per = {}
