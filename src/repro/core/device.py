@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.program import buffer_version
+from repro.core.program import Resident, buffer_version
 from repro.core.trace import tracer
 
 
@@ -75,11 +75,14 @@ class DeviceGroup:
         self.n_transfers = 0  # device_put calls for kernel inputs
         self.n_cache_hits = 0
         # Bytes each way, padding included: kernel inputs put on the device,
-        # kernel inputs served from the transfer cache instead, and outputs
-        # copied back to host buffers (``count_d2h``).
+        # kernel inputs served from the transfer cache or a ``Resident``
+        # value instead, outputs copied back to host buffers
+        # (``count_d2h``), and ``Resident`` outputs left on the device
+        # (``count_kept``).
         self.h2d_bytes = 0
         self.resident_bytes = 0
         self.d2h_bytes = 0
+        self.kept_bytes = 0
 
     @property
     def device(self) -> jax.Device:
@@ -168,16 +171,29 @@ class DeviceGroup:
                 "h2d_bytes": self.h2d_bytes,
                 "resident_bytes": self.resident_bytes,
                 "d2h_bytes": self.d2h_bytes,
+                "kept_bytes": self.kept_bytes,
                 "cached_entries": len(self._xfer_cache),
             }
 
     def count_d2h(self, nbytes: int) -> None:
-        """Count ``nbytes`` of outputs copied from this group to host."""
+        """Count ``nbytes`` copied from this group to host."""
         with self._xfer_lock:
             self.d2h_bytes += nbytes
 
+    def count_h2d(self, nbytes: int) -> None:
+        """Count one transfer of ``nbytes`` from host to this group."""
+        with self._xfer_lock:
+            self.n_transfers += 1
+            self.h2d_bytes += nbytes
+
+    def count_kept(self, nbytes: int) -> None:
+        """Count ``nbytes`` of ``Resident`` outputs left on this group."""
+        with self._xfer_lock:
+            self.kept_bytes += nbytes
+
     def _input_slice(self, program, host_buf, offset_wi: int, size_wi: int,
-                     bucket: int, *, consume: bool = False):
+                     bucket: int, *, consume: bool = False,
+                     keep_resident: bool = False):
         """Device copy of one input's package slice, padded to the bucket.
 
         Cached per (buffer version, offset, bucket): iterative/serving reruns
@@ -186,11 +202,15 @@ class DeviceGroup:
         array, so a cache hit is *popped* and fresh transfers are never
         retained — each upload/handoff serves exactly one run.
 
+        ``keep_resident`` (a run pinned to this group): a ``Resident`` input
+        is read from its device value (:meth:`_resident_slice`).
+
         Returns (device array, bytes put on the device, bytes served from
-        the cache)."""
-        r = program.buffer_ratio(host_buf)
-        lo, hi = int(r * offset_wi), int(r * (offset_wi + size_wi))
-        need = int(r * bucket) - (hi - lo)
+        the cache or a ``Resident`` value)."""
+        lo, hi = program.rows_of(host_buf, offset_wi, size_wi)
+        need = int(program.buffer_ratio(host_buf) * bucket) - (hi - lo)
+        if keep_resident and isinstance(host_buf, Resident):
+            return self._resident_slice(host_buf, lo, hi, need, consume)
         # A buffer that is both input and output of the same Program
         # (in-place update) is uncacheable: under run-scoped write versions a
         # mid-run input slice would be keyed on the run's final version and
@@ -236,6 +256,25 @@ class DeviceGroup:
         if key is not None and not consume:
             self._cache_put(key, dev, host_buf)
         return dev, b.nbytes, 0
+
+    def _resident_slice(self, buf: Resident, lo: int, hi: int, need: int,
+                        consume: bool):
+        """A ``Resident`` input's rows ``lo:hi`` on this group's device,
+        padded by ``need`` rows: the value itself when the package covers it
+        whole (taken from ``buf`` when donated: the kernel consumes it), else
+        a device-side slice.  No host copy, no transfer-cache entry."""
+        up = buf.place(self)
+        if lo == 0 and hi == len(buf) and need == 0:
+            dev = buf.take() if consume else buf.value
+        else:
+            dev = buf.value[lo:hi]
+            if need > 0:
+                dev = jnp.pad(dev, [(0, need)] + [(0, 0)] * (dev.ndim - 1))
+        if up:
+            return dev, up, 0
+        with self._xfer_lock:
+            self.resident_bytes += dev.nbytes
+        return dev, 0, dev.nbytes
 
     def stash_output(self, program, host_buf, offset_wi: int, size_wi: int,
                      dev_result, version: Optional[int]) -> None:
@@ -295,15 +334,18 @@ class DeviceGroup:
             self._xfer_cache.move_to_end(base_key)
         return True
 
-    def execute_chunk(self, program, offset_wi: int, size_wi: int):
+    def execute_chunk(self, program, offset_wi: int, size_wi: int, *,
+                      keep_resident: bool = False):
         """Run one package; returns device arrays (async, not blocked).
 
         Inputs are padded to the bucket size; callers must trim outputs to
         ``size_wi`` (Program.write_outputs does).  Staging them is the
         ``upload`` span on the group's track, with args ``bytes`` (put on
         the device) and ``resident_bytes`` (served from the transfer
-        cache).  ``jax.device_put`` may return before its copy ends, so the
-        span is the host's share of the copy; ``bytes`` is the whole of it.
+        cache or a ``Resident`` value, read on the device when
+        ``keep_resident``: the run is pinned to this group).
+        ``jax.device_put`` may return before its copy ends, so the span is
+        the host's share of the copy; ``bytes`` is the whole of it.
         """
         fn = self.compile_kernel(program)
         bucket = self._bucket(size_wi, program.lws)
@@ -314,7 +356,8 @@ class DeviceGroup:
             for i, b in enumerate(program._ins):
                 dev, up, hit = self._input_slice(program, b, offset_wi,
                                                  size_wi, bucket,
-                                                 consume=i in donated)
+                                                 consume=i in donated,
+                                                 keep_resident=keep_resident)
                 ins.append(dev)
                 h2d += up
                 resident += hit

@@ -17,12 +17,15 @@ consistently — the runtime slices work-items, never raw indices.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import threading
 import weakref
 from fractions import Fraction
 from typing import Any, Callable, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 # --------------------------------------------------------- buffer versioning
@@ -71,6 +74,179 @@ def bump_version(buf) -> None:
             _versions[key] = next(_version_counter)
 
 
+# ----------------------------------------------------- device-resident buffers
+class Resident:
+    """A Program buffer whose authoritative value lives on a device group.
+
+    Declared by shape, dtype and fill (``fill`` is what every element holds
+    before anything is written), not by a host array: nothing allocates a
+    host copy of its full size.  Its value is created on the device the
+    first time a run needs it.
+
+    A run pinned to exactly one ``DeviceGroup`` (``RunHandle.on_one_group``)
+    reads the value on that group's device and leaves its new output value
+    there: ``Runtime._write_back`` copies nothing to host for it, and it is
+    never an entry of the evictable transfer cache, so an eviction cannot
+    lose it.  A run split across several groups first brings it to host
+    (:meth:`to_host`); for that run it is an ordinary host buffer, written
+    back and handed off through the transfer cache like any other.
+
+    Host reads go through :meth:`read_back` (only the rows asked for, counted
+    in the group's ``d2h_bytes``); row writes through :func:`copy_rows` and
+    :func:`fill_rows`, which patch the device value in place."""
+
+    def __init__(self, shape, dtype, fill=0) -> None:
+        self.shape = tuple(int(s) for s in shape)
+        self.dtype = np.dtype(dtype)
+        self.fill = fill
+        self.value = None  # device array (authoritative) while on a device
+        self.group = None  # the DeviceGroup holding ``value``
+        self.host = None   # host array while a split run needs one
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    @property
+    def nbytes(self) -> int:
+        return int(np.prod(self.shape)) * self.dtype.itemsize
+
+    # Host-buffer protocol for the write-back path, legal only while on
+    # host (``to_host``: a split run); elsewhere use ``read_back``.
+    def __getitem__(self, idx):
+        return self._host()[idx]
+
+    def __setitem__(self, idx, values) -> None:
+        self._host()[idx] = values
+
+    def _host(self) -> np.ndarray:
+        if self.host is None:
+            raise RuntimeError("Resident buffer is on the device: read it "
+                               "with read_back()")
+        return self.host
+
+    def place(self, group) -> int:
+        """Make ``value`` live on ``group``'s device: created there with the
+        fill, uploaded from host (counted on ``group``), or moved from the
+        group that held it.  Returns the bytes put on the device from host."""
+        up = 0
+        if self.host is not None:
+            self.value = jax.device_put(self.host, group.device)
+            up = self.host.nbytes
+            group.count_h2d(up)
+            self.host = None
+        elif self.value is None:
+            self.value = jnp.full(self.shape, self.fill, self.dtype,
+                                  device=group.device)
+        elif self.group is not group:
+            self.value = jax.device_put(self.value, group.device)
+        self.group = group
+        return up
+
+    def take(self):
+        """Hand the device value to a kernel that donates it (the kernel
+        consumes the array; its output becomes the next value)."""
+        v, self.value = self.value, None
+        return v
+
+    def keep(self, group, lo: int, hi: int, result) -> int:
+        """Store rows ``lo:hi`` of a run's output, produced on ``group``, as
+        the new value (bucket padding trimmed on the device).  Returns the
+        bytes kept on the device."""
+        part = result if result.shape[0] == hi - lo else result[: hi - lo]
+        if lo == 0 and hi == len(self):
+            self.value, self.group, self.host = part, group, None
+        else:
+            self.place(group)
+            self.value = self.value.at[lo:hi].set(part)
+        return part.nbytes
+
+    def read_back(self, rows=None) -> np.ndarray:
+        """Host copy of ``rows`` (all rows when None), counted in the holding
+        group's ``d2h_bytes``."""
+        sel = slice(None) if rows is None else np.asarray(rows, np.intp)
+        if self.host is not None:
+            return np.array(self.host[sel])
+        if self.value is None:
+            n = len(self) if rows is None else len(sel)
+            return np.full((n,) + self.shape[1:], self.fill, self.dtype)
+        v = self.value if rows is None else self.value[jnp.asarray(sel)]
+        out = np.array(v)
+        self.group.count_d2h(out.nbytes)
+        return out
+
+    def to_host(self) -> np.ndarray:
+        """Bring the value to host (read back whole, or the fill when never
+        written) for a run split across groups; the host array is then the
+        authoritative copy until a pinned run places it again."""
+        if self.host is None:
+            self.host = self.read_back()
+            self.value = None
+        return self.host
+
+    def clear(self) -> None:
+        """Drop the value (a swapped-out input about to be overwritten)."""
+        self.value = self.group = self.host = None
+        bump_version(self)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_rows(dsts, srcs, idx):
+    return tuple(d.at[idx].set(s.astype(d.dtype)) for d, s in zip(dsts, srcs))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(2,))
+def _fill_rows(dsts, idx, value):
+    return tuple(d.at[idx].set(value) for d in dsts)
+
+
+def copy_rows(dsts: Sequence[Resident], rows, srcs: Sequence, group) -> None:
+    """Write ``srcs[k]`` (one source row per entry of ``rows``: a
+    ``Resident``, a device array or a host array) into rows ``rows`` of
+    ``dsts[k]`` on the device, in one jitted, donated scatter for the whole
+    set: only the rows move.  A never-written destination is created on
+    ``group``; host sources are uploaded (counted on the group); a
+    destination on host (a split run's) is written there."""
+    idx = np.asarray(rows, np.int32)
+    live, vals = [], []
+    for d, s in zip(dsts, srcs):
+        if isinstance(s, Resident):
+            s = s.value if s.value is not None else s.read_back()
+        if d.host is not None:
+            d.host[idx] = np.asarray(s)
+            bump_version(d)
+            continue
+        d.place(d.group or group)
+        if not isinstance(s, jax.Array):
+            d.group.count_h2d(np.asarray(s).nbytes)
+        live.append(d)
+        vals.append(s)
+    if live:
+        out = _scatter_rows(tuple(d.value for d in live), tuple(vals),
+                            jnp.asarray(idx))
+        for d, v in zip(live, out):
+            d.value = v
+            bump_version(d)
+
+
+def fill_rows(dsts: Sequence[Resident], rows, value, group) -> None:
+    """Set rows ``rows`` of every buffer in ``dsts`` to ``value`` on the
+    device (one jitted, donated scatter), or on host for a split run's."""
+    idx = np.asarray(rows, np.int32)
+    live = []
+    for d in dsts:
+        if d.host is not None:
+            d.host[idx] = value
+            bump_version(d)
+        else:
+            d.place(d.group or group)
+            live.append(d)
+    if live:
+        out = _fill_rows(tuple(d.value for d in live), jnp.asarray(idx), value)
+        for d, v in zip(live, out):
+            d.value = v
+            bump_version(d)
+
+
 class Program:
     def __init__(self) -> None:
         self._ins: list[Any] = []
@@ -94,7 +270,7 @@ class Program:
         return self
 
     def out(self, buf) -> "Program":
-        self._outs.append(np.asarray(buf))
+        self._outs.append(buf if isinstance(buf, Resident) else np.asarray(buf))
         return self
 
     def out_pattern(self, out_elems: int, work_items: int = 1) -> "Program":
@@ -208,6 +384,11 @@ class Program:
     def buffer_ratio(self, buf) -> Fraction:
         return Fraction(len(buf), self.gws)
 
+    def rows_of(self, buf, offset_wi: int, size_wi: int) -> tuple:
+        """Element bounds ``(lo, hi)`` of ``buf`` for a work-item range."""
+        r = self.buffer_ratio(buf)
+        return int(r * offset_wi), int(r * (offset_wi + size_wi))
+
     def slice_inputs(self, offset_wi: int, size_wi: int) -> list:
         """Slice every input buffer for a work-item range."""
         out = []
@@ -218,7 +399,7 @@ class Program:
         return out
 
     def write_outputs(self, offset_wi: int, size_wi: int, results: Sequence,
-                      *, bump: bool = True) -> int:
+                      *, bump: bool = True, keep_resident: bool = False) -> int:
         """Write one package's results back to the host output buffers.
 
         ``bump=True`` (the default, tier-1 semantics) re-versions each buffer
@@ -227,6 +408,9 @@ class Program:
         so every chunk a run produces shares a single coherent version — the
         precondition for serving still-on-device output slices to dependent
         runs from the transfer cache.
+
+        ``keep_resident`` skips the ``Resident`` outputs: a run pinned to one
+        group leaves them on the device (``Resident.keep``).
 
         Returns the bytes copied to host, bucket padding included: the whole
         result crosses before it is trimmed."""
@@ -238,8 +422,9 @@ class Program:
             )
         nbytes = 0
         for b, res in zip(self._outs, results):
-            r = self.buffer_ratio(b)
-            lo, hi = int(r * offset_wi), int(r * (offset_wi + size_wi))
+            if keep_resident and isinstance(b, Resident):
+                continue
+            lo, hi = self.rows_of(b, offset_wi, size_wi)
             host = np.asarray(res)
             nbytes += host.nbytes
             b[lo:hi] = host[: hi - lo]  # trim bucket padding
@@ -257,11 +442,25 @@ class Program:
         still-on-device result slices stay servable from the transfer cache —
         iterative chains hand buffers off device-resident instead of
         re-uploading.  The fresh output copy is a new array the cache has
-        never seen; bumping it is a defensive no-op."""
+        never seen; bumping it is a defensive no-op.  A ``Resident`` pair
+        swaps objects: the produced value (still on the device) becomes the
+        input, and the old input, consumed or stale, is cleared."""
         new_in = self._outs[i_out]
-        new_out = np.ascontiguousarray(self._ins[i_in])
+        old_in = self._ins[i_in]
+        if isinstance(old_in, Resident):
+            old_in.clear()
+            new_out = old_in
+        else:
+            new_out = np.ascontiguousarray(old_in)
         self._ins[i_in], self._outs[i_out] = new_in, new_out
         bump_version(new_out)
+
+    def to_host(self) -> None:
+        """Bring every ``Resident`` buffer to host: a run split across
+        several groups reads and writes them there."""
+        for b in self._ins + self._outs:
+            if isinstance(b, Resident):
+                b.to_host()
 
     def invalidate(self, buf=None) -> None:
         """Mark host buffers as externally modified (drops cached transfers).

@@ -41,7 +41,7 @@ import jax
 from repro.core.device import DeviceGroup
 from repro.core.introspector import Introspector, PackageRecord
 from repro.core.obs import bus as obs_bus
-from repro.core.program import Program, buffer_version, bump_version
+from repro.core.program import Program, Resident, buffer_version, bump_version
 from repro.core.scheduler.base import Scheduler
 from repro.core.trace import tracer
 
@@ -114,6 +114,13 @@ class RunHandle:
         self.read_ids = frozenset(map(id, program._ins))
         self.write_ids = frozenset(map(id, program._outs))
 
+    @property
+    def on_one_group(self) -> bool:
+        """Pinned to exactly one device group: the run keeps its Program's
+        ``Resident`` buffers on that group's device (no host write-back); a
+        run split across several groups brings them to host first."""
+        return len(self.targets) == 1
+
     # -- worker-facing -----------------------------------------------------
     def _mark_started(self) -> None:
         """First worker to pick up the run stamps t_run_start — metrics of
@@ -129,12 +136,16 @@ class RunHandle:
         the first worker that actually starts the run — not at submit time —
         so queued runs of a dependency chain read geometry/powers when they
         begin, and every worker observes a fully-prepared scheduler before
-        its first ``next_package``."""
+        its first ``next_package``.  The same first worker brings the
+        Program's ``Resident`` buffers to host when the run is split across
+        groups, before any worker slices them."""
         with self._lock:
             first = not self._prepared
             self._prepared = True
         if first:
             try:
+                if not self.on_one_group:
+                    self.program.to_host()
                 self.scheduler.prepare(
                     self.program.n_work_groups, self.program.lws, groups
                 )
@@ -489,19 +500,21 @@ class Runtime:
         if not ok:
             return
         handle._mark_started()
-        handle._ensure_prepared(handle.targets or self.groups)
         # Per-run transfer accounting: runs on one group serialize on its
         # worker thread, so the cumulative-counter delta around this run is
         # exactly what this run caused on this group.
         xfer0, hits0 = group.n_transfers, group.n_cache_hits
+        keep = handle.on_one_group
         pending: list = []  # (offset, size, result, t_enqueue)
         try:
+            handle._ensure_prepared(handle.targets or self.groups)
             while True:
                 pkg = sched.next_package(group)
                 if pkg is not None:
                     off, size = pkg
                     t_enq = time.perf_counter()
-                    res = group.execute_chunk(prog, off, size)  # async dispatch
+                    res = group.execute_chunk(prog, off, size,  # async
+                                              keep_resident=keep)
                     if tr.enabled:
                         # Host-side dispatch cost only: the device compute is
                         # still in flight — it becomes the "execute" span.
@@ -527,8 +540,9 @@ class Runtime:
                     service = t_end - t_enq
                     with tr.span("write_back", track=track, offset=off,
                                  size=size) as sp:
-                        sp.set(bytes=self._write_back(group, handle, off,
-                                                      size, res))
+                        nbytes, kept = self._write_back(group, handle, off,
+                                                        size, res)
+                        sp.set(bytes=nbytes, kept_bytes=kept)
                     handle.introspector.record(
                         PackageRecord(group.name, off, size, t_enq, t_enq, t_end)
                     )
@@ -545,16 +559,30 @@ class Runtime:
                 group.n_cache_hits - hits0)
 
     def _write_back(self, group: DeviceGroup, handle: RunHandle,
-                    off: int, size: int, res) -> int:
+                    off: int, size: int, res) -> tuple:
         """Host write-back + device-resident handoff: the produced device
         slices are stashed in this group's transfer cache under the run's
         write version, so a dependent run reading the same elements on the
         same group skips the host re-read and the ``jax.device_put``.
-        Returns (and counts on ``group``) the bytes copied to host."""
+
+        A run pinned to one group copies nothing to host for its
+        ``Resident`` outputs: their device results become the buffers'
+        values (``Resident.keep``), outside the evictable cache.
+
+        Returns (and counts on ``group``) the bytes copied to host and the
+        ``Resident`` bytes kept on the device."""
         prog = handle.program
         results = res if isinstance(res, (tuple, list)) else (res,)
-        nbytes = prog.write_outputs(off, size, results, bump=False)
-        group.count_d2h(nbytes)
+        keep = handle.on_one_group
+        nbytes = prog.write_outputs(off, size, results, bump=False,
+                                    keep_resident=keep)
+        kept = 0
         for b, r in zip(prog._outs, results):
-            group.stash_output(prog, b, off, size, r, handle.version_for_write(b))
-        return nbytes
+            version = handle.version_for_write(b)
+            if keep and isinstance(b, Resident):
+                kept += b.keep(group, *prog.rows_of(b, off, size), r)
+            else:
+                group.stash_output(prog, b, off, size, r, version)
+        group.count_d2h(nbytes)
+        group.count_kept(kept)
+        return nbytes, kept

@@ -15,21 +15,31 @@ batch and the runtime —
   leading cache rows) and a *decode-segment* kernel (``seg_len`` per-slot
   decode steps rolled into one ``lax.scan``).
 - ``BatchGroup``     — one live continuous batch: ``n_slots`` KV-cache
-  slots backed by slot-leading host mirror buffers that form a single
-  ``Program``, decoding in fixed-length segments submitted through
+  slots backed by slot-leading buffers that form a single ``Program``,
+  decoding in fixed-length segments submitted through
   ``Runtime.submit(after=prev_segment)``.
+
+Where the cache lives: when every run of the batch executes on one device
+group (``BatchGroup.home``: a sole group, or a ``group_batches`` member),
+the cache leaves are ``Resident`` buffers — the device holds the only
+copy, the runtime writes nothing of them back, and no host mirror exists.
+Otherwise (the slot axis split across groups; the paged pool) they are
+host mirrors, written back after every run.
 
 The segment Program's inputs are the previous segment's outputs, ping-pong
 swapped by the run epilogue (``swap_buffers``) — so segment N+1 reads
-segment N's token/position/cache buffers **device-resident** from the
-transfer cache (the one-bump-per-(run, buffer) rule: each segment's outputs
-carry one coherent write version that the next segment's input probe looks
-up; ``swap_buffers`` deliberately does not re-version the swapped-in
-buffer).  Steady-state decode therefore performs zero host→device
-transfers; only join events — which rewrite slot rows in the host mirrors
-and must ``invalidate`` them — pay a re-upload.  Per-request transfers stay
-O(1) however many segments its decode spans (asserted in
-tests/test_server.py via ``DeviceGroup.n_transfers``).
+segment N's cache as the ``Resident`` values it left on the device, and
+its small token/position buffers **device-resident** from the transfer
+cache (the one-bump-per-(run, buffer) rule: each segment's outputs carry
+one coherent write version that the next segment's input probe looks up;
+``swap_buffers`` deliberately does not re-version the swapped-in buffer).
+Steady-state decode therefore performs zero host→device transfers, and
+copies only tokens and positions to host.  A join writes the small buffers
+on host and re-uploads them, and copies the joiners' cache rows from the
+prefill's device outputs on the device (host mirrors: rewritten and
+re-uploaded whole).  Per-request transfers stay O(1) however many segments
+its decode spans (asserted in tests/test_server.py via
+``DeviceGroup.n_transfers``).
 
 Requests *exit* at segment boundaries (their slot is left to decode
 garbage — shapes are static — until a joiner overwrites the full slot row,
@@ -54,7 +64,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core.program import Program
+from repro.core.program import Program, Resident, copy_rows, fill_rows
 from repro.core.trace import tracer
 from repro.serve.step import (
     DraftSpec,
@@ -171,31 +181,24 @@ class ModelKernels:
             is_leaf=lambda x: isinstance(x, Spec),
         )
 
-    def leaf_mirrors(self, n_slots: int, max_seq: int) -> List[np.ndarray]:
-        """Slot-leading host mirror buffers for every cache leaf, honoring
-        each leaf's declared init (position leaves are −1 = empty, the same
-        contract ``zeros_cache`` enforces on device)."""
-        out = []
-        for s, a in zip(self._leaf_specs(max_seq), self.bax_leaves):
-            dt = np.dtype(s.dtype or self.cfg.compute_dtype)
-            shape = s.shape[:a] + s.shape[a + 1:]
-            fill = {"neg_ones": -1, "ones": 1}.get(s.init, 0)
-            out.append(np.full((n_slots,) + shape, fill, dt))
-        return out
+    def leaf_buffers(self, n_slots: int, max_seq: int, *,
+                     resident: bool = False) -> list:
+        """Slot-leading buffers for every cache leaf, honoring each leaf's
+        declared init (position leaves are −1 = empty, the same contract
+        ``zeros_cache`` enforces on device): host mirrors, or ``Resident``
+        buffers created on the device when ``resident``."""
+        return _slot_buffers(self._leaf_specs(max_seq), self.bax_leaves,
+                             self.cfg.compute_dtype, n_slots, resident)
 
-    def draft_leaf_mirrors(self, n_slots: int, max_seq: int) -> List[np.ndarray]:
-        """Slot-leading mirrors for the *draft* model's cache.  Always
+    def draft_leaf_buffers(self, n_slots: int, max_seq: int, *,
+                           resident: bool = False) -> list:
+        """Slot-leading buffers for the *draft* model's cache.  Always
         contiguous slot rows — even when the target cache is paged, the
         draft cache is small (shallow config) and transient (it carries no
         bit-identity obligation: its staleness only moves the acceptance
         rate), so paging it would buy nothing."""
-        out = []
-        for s, a in zip(self._draft_leaf_specs(max_seq), self.dbax_leaves):
-            dt = np.dtype(s.dtype or self.draft.cfg.compute_dtype)
-            shape = s.shape[:a] + s.shape[a + 1:]
-            fill = {"neg_ones": -1, "ones": 1}.get(s.init, 0)
-            out.append(np.full((n_slots,) + shape, fill, dt))
-        return out
+        return _slot_buffers(self._draft_leaf_specs(max_seq), self.dbax_leaves,
+                             self.draft.cfg.compute_dtype, n_slots, resident)
 
     def leaf_neg_init(self, max_seq: int) -> List[bool]:
         """Which cache leaves record positions (init ``neg_ones``) — the
@@ -808,8 +811,11 @@ class BatchGroup:
         self.tokens_written = 0  # KV positions actually written (memory_stats)
         self.last_run_metrics: dict = {}
         self.telemetry = None  # set by the owning InferenceServer
-        # Host mirrors of the whole cache, filled in full (about 1.3 GB for
-        # a qwen1.5-4b bucket-256 group): the batcher track's ``form_group``.
+        # The one device group every run of this batch executes on; its
+        # cache leaves are then ``Resident`` buffers, kept on that group's
+        # device.  None: runs split across groups, or a kernel-only group
+        # (no runtime), keep host mirrors of the cache.
+        self.home = self._home_group()
         with tracer().span("form_group", track="batcher", bucket=bucket):
             self._build_segment_program()
         self.seg_handle = None
@@ -821,13 +827,33 @@ class BatchGroup:
         self._prefill_prog: Optional[Program] = None
         self._prefill_t0 = 0.0
 
+    def _home_group(self):
+        """The runtime's one device group when this batch's runs are pinned
+        to exactly one (``RunHandle.on_one_group``), else None."""
+        if self.runtime is None:
+            return None
+        groups = self.target or self.runtime.groups
+        return groups[0] if len(groups) == 1 else None
+
+    def _leaf_buffers(self, n_slots: int) -> list:
+        """Target- then (speculating) draft-cache buffers of ``n_slots``
+        rows: ``Resident`` on :attr:`home`, else host mirrors."""
+        resident = self.home is not None
+        leaves = self.kernels.leaf_buffers(n_slots, self.max_seq,
+                                           resident=resident)
+        if self.spec_k:
+            leaves += self.kernels.draft_leaf_buffers(n_slots, self.max_seq,
+                                                      resident=resident)
+        return leaves
+
     def _build_segment_program(self) -> None:
-        """Contiguous layout: slot-leading mirrors, ping-pong in/out pairs
-        (PagedBatchGroup overrides this with pool buffers + block table)."""
+        """Contiguous layout: slot-leading cache leaves, ping-pong in/out
+        pairs (PagedBatchGroup overrides this with pool buffers + block
+        table)."""
         kernels, n_slots, seg_len = self.kernels, self.n_slots, self.seg_len
         tok = np.zeros((n_slots, 1), np.int32)
         pos = np.zeros((n_slots, 1), np.int32)
-        leaves = kernels.leaf_mirrors(n_slots, self.max_seq)
+        leaves = self._leaf_buffers(n_slots)
         if self.chunk_len:
             self._build_mixed_program(tok, pos, leaves)
             return
@@ -840,7 +866,6 @@ class BatchGroup:
             # seg_len*(k+1) with a per-slot count of how much is real.
             k = self.spec_k
             ptok = np.zeros((n_slots, 1), np.int32)
-            leaves = leaves + kernels.draft_leaf_mirrors(n_slots, self.max_seq)
             toks_seg = np.zeros((n_slots, seg_len * (k + 1)), np.int32)
             prog = kernels.program().in_(tok).in_(ptok).in_(pos)
             for b in leaves:
@@ -854,7 +879,7 @@ class BatchGroup:
             prog.out(np.zeros_like(tok)).out(np.zeros_like(ptok))
             prog.out(np.zeros_like(pos))
             for b in leaves:
-                prog.out(np.zeros_like(b))
+                prog.out(_blank(b))
             prog.kernel(kernels.spec_segment_kernel(seg_len),
                         f"spec_seg{seg_len}_k{k}")
             prog.donate(*range(3, 3 + len(leaves)))
@@ -873,7 +898,7 @@ class BatchGroup:
             prog.in_(b)
         prog.out(toks_seg).out(np.zeros_like(tok)).out(np.zeros_like(pos))
         for b in leaves:
-            prog.out(np.zeros_like(b))
+            prog.out(_blank(b))
         prog.kernel(kernels.segment_kernel(seg_len), f"decode_seg{seg_len}")
         # Donate the cache-leaf inputs (mirroring make_generate's
         # donate_argnums=(1,)): each segment's jitted kernel updates the KV
@@ -904,7 +929,6 @@ class BatchGroup:
         if self.spec_k:
             k = self.spec_k
             ptok = np.zeros((n_slots, 1), np.int32)
-            leaves = leaves + kernels.draft_leaf_mirrors(n_slots, self.max_seq)
             toks_seg = np.zeros((n_slots, seg_len * (k + 1)), np.int32)
             prog = (kernels.program().in_(tok).in_(ptok).in_(pos).in_(pcur)
                     .in_(ptoks))
@@ -917,7 +941,7 @@ class BatchGroup:
             prog.out(np.zeros_like(pos)).out(np.zeros_like(pcur))
             prog.out(np.zeros_like(tok))  # ctok
             for b in leaves:
-                prog.out(np.zeros_like(b))
+                prog.out(_blank(b))
             prog.kernel(
                 kernels.spec_mixed_segment_kernel(seg_len, self.bucket,
                                                   self.chunk_len),
@@ -938,7 +962,7 @@ class BatchGroup:
         prog.out(toks_seg).out(np.zeros_like(tok)).out(np.zeros_like(pos))
         prog.out(np.zeros_like(pcur)).out(np.zeros_like(tok))  # pcur', ctok
         for b in leaves:
-            prog.out(np.zeros_like(b))
+            prog.out(_blank(b))
         prog.kernel(
             kernels.mixed_segment_kernel(seg_len, self.bucket, self.chunk_len),
             f"mixed_seg{seg_len}_b{self.bucket}_c{self.chunk_len}")
@@ -1035,17 +1059,13 @@ class BatchGroup:
             prog.out(np.zeros((j, 1), np.int32))
             if self.spec_k:
                 prog.out(np.zeros((j, 1), np.int32))  # ptok0
-                for b in self.kernels.leaf_mirrors(j, self.max_seq):
-                    prog.out(b)
-                for b in self.kernels.draft_leaf_mirrors(j, self.max_seq):
-                    prog.out(b)
                 prog.kernel(self.kernels.spec_prefill_kernel(self.max_seq),
                             f"spec_prefill_{self.bucket}")
             else:
-                for b in self.kernels.leaf_mirrors(j, self.max_seq):
-                    prog.out(b)
                 prog.kernel(self.kernels.prefill_kernel(self.max_seq),
                             f"prefill_{self.bucket}")
+            for b in self._leaf_buffers(j):
+                prog.out(b)
             prog.work_items(j, 1)
             self._prefill_prog = prog
             h = self.runtime.submit(prog, self.scheduler, groups=self.target)
@@ -1053,11 +1073,15 @@ class BatchGroup:
         h.add_done_callback(lambda _h: notify())
 
     def merge_prefill(self) -> dict:
-        """Board a completed prefill wave: write each request's first token,
-        start position, and full cache row into a free slot's host mirrors,
-        then invalidate the mirrors (their device copies are stale).  Only
-        legal between segments — an in-flight segment may slice the mirrors
-        at any moment.  Returns {"joined": n, "failed": [...], "seconds"}.
+        """Board a completed prefill wave: write each request's first token
+        and start position into a free slot's row of the small host buffers
+        (then invalidate them: their device copies are stale), and its
+        cache rows into the segment's cache leaves — on the device, one
+        donated row scatter for the wave (:func:`copy_rows`), when the
+        leaves are ``Resident``; into the host mirrors, invalidated whole,
+        when runs are split across groups.  Only legal between segments — an
+        in-flight segment may read the buffers at any moment.  Returns
+        {"joined": n, "failed": [...], "seconds"}.
         The server calls it under its lock, in a ``merge`` span: ``submit``
         waits behind it."""
         h, wave, prog = self.prefill_handle, self.prefill_wave, self._prefill_prog
@@ -1083,21 +1107,30 @@ class BatchGroup:
             leaf_bufs = self.prog._ins[2:]
             tok0, ptok0 = prog._outs[0], None
             wave_leaves = prog._outs[1:]
-        for i, req in enumerate(wave):
-            slot = free.pop(0)
+        slots = free[:len(wave)]
+        for i, (req, slot) in enumerate(zip(wave, slots)):
             tok_b[slot, 0] = tok0[i, 0]
             if ptok_b is not None:
                 ptok_b[slot, 0] = ptok0[i, 0]
             pos_b[slot, 0] = self.bucket
-            for dst, src in zip(leaf_bufs, wave_leaves):
-                dst[slot] = src[i]
+            if self.home is None:
+                for dst, src in zip(leaf_bufs, wave_leaves):
+                    dst[slot] = src[i]
             self.slots[slot] = req
             req.board(slot, int(tok0[i, 0]))
             if tr.enabled:
                 tr.async_instant("first_token", req.seq, slot=slot)
         self.tokens_written += len(wave) * min(self.bucket, self.max_seq)
-        for b in self.prog._ins:
-            self.prog.invalidate(b)
+        if self.home is None:
+            for b in self.prog._ins:
+                self.prog.invalidate(b)
+        else:
+            copy_rows(leaf_bufs, slots, wave_leaves, self.home)
+            for b in (tok_b, ptok_b, pos_b):
+                if b is not None:
+                    self.prog.invalidate(b)
+            for b in wave_leaves:
+                b.clear()  # the wave's cache rows: free them now
         return {"joined": len(wave), "failed": [], "seconds": seconds}
 
     def _merge_chunked(self, wave, seconds: float) -> dict:
@@ -1107,8 +1140,9 @@ class BatchGroup:
         under kpos −1 is never attended, so the big value leaves stay
         device-resident) — and defer ``req.board`` to the harvest of the
         segment whose chunk completes the prompt (``ctok``).  The join
-        re-uploads only the small control buffers + position leaves instead
-        of full slot-rows of every cache leaf."""
+        re-uploads only the small control buffers; the position-leaf rows
+        are set on the device (:func:`fill_rows`) when the leaves are
+        ``Resident``, else in the host mirrors, re-uploaded whole."""
         free = self.free_slots()
         if self.spec_k:
             tok_b, ptok_b, pos_b = (self.prog._ins[0], self.prog._ins[1],
@@ -1122,16 +1156,17 @@ class BatchGroup:
             pcur_b, ptoks_b = self.prog._ins[2], self.prog._ins[3]
             leaf_bufs = self.prog._ins[4:]
             neg = self.kernels.leaf_neg_init(self.max_seq)
-        for req in wave:
-            slot = free.pop(0)
+        pos_leaves = [dst for dst, is_neg in zip(leaf_bufs, neg) if is_neg]
+        slots = free[:len(wave)]
+        for req, slot in zip(wave, slots):
             tok_b[slot, 0] = 0
             if ptok_b is not None:
                 ptok_b[slot, 0] = int(req.prompt[-1])
             pos_b[slot, 0] = self.bucket
             pcur_b[slot, 0] = 0
             ptoks_b[slot, :] = req.prompt
-            for dst, is_neg in zip(leaf_bufs, neg):
-                if is_neg:
+            if self.home is None:
+                for dst in pos_leaves:
                     dst[slot] = -1
             self.slots[slot] = req
             req.slot = slot
@@ -1139,9 +1174,11 @@ class BatchGroup:
         for b in (tok_b, ptok_b, pos_b, pcur_b, ptoks_b):
             if b is not None:
                 self.prog.invalidate(b)
-        for dst, is_neg in zip(leaf_bufs, neg):
-            if is_neg:
+        if self.home is None:
+            for dst in pos_leaves:
                 self.prog.invalidate(dst)
+        else:
+            fill_rows(pos_leaves, slots, -1, self.home)
         return {"joined": len(wave), "failed": [], "seconds": seconds}
 
     # ------------------------------------------------------------ segments
@@ -1280,9 +1317,11 @@ class BatchGroup:
 
     # ------------------------------------------------------------ migration
     def at_boundary(self) -> bool:
-        """True between runs: no segment or prefill in flight, so the host
-        mirrors are the authoritative slot state (every package was written
-        back and the epilogue swap ran)."""
+        """True between runs: no segment or prefill in flight, so
+        ``prog._ins`` hold the authoritative slot state (the epilogue swap
+        ran): the token/position buffers on host (every package wrote them
+        back), the cache leaves on :attr:`home`'s device when ``Resident``,
+        else in host mirrors (written back)."""
         return self.seg_handle is None and self.prefill_handle is None
 
     def can_accept_migration(self, src: "BatchGroup", slot: int) -> bool:
@@ -1296,9 +1335,11 @@ class BatchGroup:
         """Move one active request — tokens, positions, and its entire KV
         slot state — into a free slot of ``dst``.  Legal only at a segment
         boundary on both sides: after the epilogue swap, ``prog._ins`` rows
-        ARE the current state (write-back keeps host mirrors coherent), so
-        migration is a host row copy plus an O(rows)/O(blocks) device patch
-        (:meth:`DeviceGroup.patch_cached`) — never a full-cache rewrite.
+        ARE the current state (:meth:`at_boundary`), so migration moves
+        O(rows)/O(blocks): host rows patched into the destination's device
+        copies (:meth:`DeviceGroup.patch_cached`), ``Resident`` cache rows
+        read back from the source device and scattered into the
+        destination's (:func:`copy_rows`) — never a full-cache rewrite.
         The stream stays bit-identical: decode is deterministic in the slot
         state, and the copied rows are exactly the state the source would
         have decoded from.  Returns False (no partial effects) when either
@@ -1324,11 +1365,17 @@ class BatchGroup:
 
     def _copy_slot_state(self, slot: int, dst: "BatchGroup", d: int) -> bool:
         """Contiguous layout: copy the slot row of every input buffer
-        (token/pos controls + every cache-leaf mirror) into ``dst``'s row
-        ``d`` and propagate the rows to ``dst``'s device copies."""
+        (token/pos controls + every cache leaf) into ``dst``'s row ``d``:
+        host rows patched into ``dst``'s device copies, ``Resident`` rows
+        read back (O(rows)) and scattered into ``dst``'s device values."""
         for src_buf, dst_buf in zip(self._row_bufs(), dst._row_bufs()):
-            dst_buf[d] = src_buf[slot]
-            dst._patch_or_invalidate(dst_buf, [d])
+            row = (src_buf.read_back([slot]) if isinstance(src_buf, Resident)
+                   else src_buf[slot:slot + 1])
+            if isinstance(dst_buf, Resident):
+                copy_rows([dst_buf], [d], [row], dst.home)
+            else:
+                dst_buf[d] = row[0]
+                dst._patch_or_invalidate(dst_buf, [d])
         return True
 
     def _patch_or_invalidate(self, buf: np.ndarray, rows: Sequence[int]) -> None:
@@ -1352,6 +1399,24 @@ class BatchGroup:
         self.seg_handle = None
         self.prefill_handle = None
         return victims
+
+
+def _slot_buffers(specs, axes, dtype, n_slots: int, resident: bool) -> list:
+    out = []
+    for s, a in zip(specs, axes):
+        dt = np.dtype(s.dtype or dtype)
+        shape = (n_slots,) + s.shape[:a] + s.shape[a + 1:]
+        fill = {"neg_ones": -1, "ones": 1}.get(s.init, 0)
+        out.append(Resident(shape, dt, fill) if resident
+                   else np.full(shape, fill, dt))
+    return out
+
+
+def _blank(buf):
+    """A fresh output buffer shaped like ``buf`` (an in/out pair's out)."""
+    if isinstance(buf, Resident):
+        return Resident(buf.shape, buf.dtype, buf.fill)
+    return np.zeros_like(buf)
 
 
 def _now() -> float:

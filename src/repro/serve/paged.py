@@ -351,12 +351,17 @@ class PagedBatchGroup(BatchGroup):
         super().__init__(kernels, runtime, scheduler, bucket, n_slots,
                          seg_len, max_seq, chunk_len=chunk_len, target=target)
 
+    def _home_group(self):
+        """None: pool leaves keep host mirrors and write-back (joins scatter
+        prefill rows block-wise into them)."""
+        return None
+
     # ----------------------------------------------------- program assembly
     def _build_segment_program(self):
         kernels, n_slots, bl = self.kernels, self.n_slots, self.block_len
         n_blocks = pool_blocks(self.spec, n_slots, self.nmax)
         if self.state.pool is None:
-            leaves = kernels.leaf_mirrors(n_blocks, bl)
+            leaves = kernels.leaf_buffers(n_blocks, bl)
             self.state.pool = BlockPool(
                 n_blocks, block_len=bl,
                 bytes_per_block=sum(b.nbytes for b in leaves) // n_blocks,
@@ -387,7 +392,7 @@ class PagedBatchGroup(BatchGroup):
             # group's draft rows belong to no live request.
             k = self.spec_k
             ptok = np.zeros((n_slots, 1), np.int32)
-            dleaves = kernels.draft_leaf_mirrors(n_slots, self.max_seq)
+            dleaves = kernels.draft_leaf_buffers(n_slots, self.max_seq)
             all_leaves = leaves + dleaves
             toks_seg = np.zeros((n_slots, self.seg_len * (k + 1)), np.int32)
             prog = kernels.program().in_(tok).in_(ptok).in_(pos).in_(self.table)
@@ -449,7 +454,7 @@ class PagedBatchGroup(BatchGroup):
         if self.spec_k:
             k = self.spec_k
             ptok = np.zeros((n_slots, 1), np.int32)
-            all_leaves = leaves + kernels.draft_leaf_mirrors(n_slots,
+            all_leaves = leaves + kernels.draft_leaf_buffers(n_slots,
                                                              self.max_seq)
             toks_seg = np.zeros((n_slots, seg_len * (k + 1)), np.int32)
             prog = (kernels.program().in_(tok).in_(ptok).in_(pos).in_(pcur)

@@ -19,6 +19,7 @@ from repro.serve import (
     AdmissionError,
     Buckets,
     DeadlineAdmission,
+    DraftSpec,
     InferenceServer,
     PagedSpec,
     ServiceModel,
@@ -98,9 +99,10 @@ def test_poisson_arrivals_bit_identical_with_real_batching(model, reference):
     assert s["completed"] == 32
     assert s["mean_occupancy"] > 1.0, s
     # Device-resident segment chaining: transfers are paid per prefill wave
-    # (prompt upload) and per merge (mirror invalidation re-upload of the
-    # segment Program's inputs) — never per decode segment.
-    n_ins = 2 + len(srv.kernels.bax_leaves)  # tok, pos, cache leaves
+    # (prompt upload) and per merge (re-upload of the segment Program's
+    # token and position buffers; the cache leaves stay on the device and
+    # take the joiners' rows there) — never per decode segment.
+    n_ins = 2  # tok, pos
     waves = s["prefill_waves"]
     assert s["segments"] > waves, s  # decode really was multi-segment
     assert g.n_transfers <= waves * (1 + n_ins), (g.transfer_stats(), s)
@@ -164,11 +166,66 @@ def test_midstream_join_exit_and_transfer_counters(model, reference):
     assert s["midstream_joins"] >= 1, s
     assert s["segments"] > s["prefill_waves"] + 1, s
     # Exact transfer accounting on a single Static group: one prompt upload
-    # per prefill wave + one re-upload of the segment inputs per merge.
-    n_ins = 2 + len(srv.kernels.bax_leaves)
+    # per prefill wave + one re-upload of the token and position buffers
+    # per merge; the cache leaves never cross.
+    n_ins = 2
     assert g.n_transfers == s["prefill_waves"] * (1 + n_ins), (
         g.transfer_stats(), s
     )
+    # To host: each segment's tokens, token and position buffers, and each
+    # joiner's first token — nothing of the cache.  Kept on the device:
+    # each segment's whole cache and each joiner's prefill rows.
+    slots, seg = 4, 2
+    joins = len(first) + len(second)
+    st = g.transfer_stats()
+    assert st["d2h_bytes"] == (s["segments"] * slots * (seg + 2) * 4
+                               + joins * 4), (st, s)
+    row = srv.kernels.leaf_buffers(1, srv._max_seq(PLEN), resident=True)
+    row_bytes = sum(b.nbytes for b in row)
+    assert st["kept_bytes"] == (s["segments"] * slots + joins) * row_bytes, (
+        st, s)
+
+
+@pytest.mark.parametrize("mode", ["plain", "spec", "chunked"])
+def test_midstream_join_resident_cache_bit_identical(model, reference, mode):
+    """A join into a running batch whose cache lives on the device (one
+    group): every stream equals its reference in plain, speculative and
+    chunked decode, and what each segment copies to host is bounded by its
+    token and position buffers — far below one slot's cache."""
+    cfg, api, params = model
+    g = DeviceGroup(f"resident-{mode}")
+    kw, k = {}, 0
+    if mode == "spec":
+        kw["draft"], k = DraftSpec(cfg, params, k=2), 2
+    if mode == "chunked":
+        kw["chunk_len"] = 4
+    slots, seg = 4, 2
+    with InferenceServer(cfg, api, params, groups=[g], scheduler=Static(),
+                         buckets=(PLEN,), max_batch=slots, seg_len=seg,
+                         max_new_cap=10, max_wait_ms=1.0, **kw) as srv:
+        first = prompts_for(cfg, 23, 2)
+        h1 = [srv.submit(p, 10) for p in first]
+        deadline = time.monotonic() + 60
+        while srv.stats()["segments"] < 1:
+            assert time.monotonic() < deadline, "first segment never finished"
+            time.sleep(0.005)
+        second = prompts_for(cfg, 24, 2)
+        h2 = [srv.submit(p, 4) for p in second]
+        for p, h in zip(first + second, h1 + h2):
+            np.testing.assert_array_equal(
+                h.result(timeout=300), reference(p, h.max_new_tokens))
+        s = srv.stats()
+        row = srv.kernels.leaf_buffers(1, srv._max_seq(PLEN))
+    assert s["midstream_joins"] >= 1, s
+    st = g.transfer_stats()
+    # Per segment: the token buffer (seg_len * (k + 1) per slot) plus at
+    # most five one-column buffers (cnt, tok, ptok, pos | pcur, ctok); per
+    # joiner: its first token and predecessor.
+    per_segment = slots * (seg * (k + 1) + 5) * 4
+    assert st["d2h_bytes"] <= s["segments"] * per_segment + 4 * 2 * 4, (st, s)
+    assert st["d2h_bytes"] < sum(b.nbytes for b in row), (st, s)
+    assert st["kept_bytes"] >= s["segments"] * slots * sum(
+        b.nbytes for b in row), (st, s)
 
 
 def test_coexec_slot_splitting_stays_bit_identical(model, reference):

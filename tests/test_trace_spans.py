@@ -20,6 +20,7 @@ from repro.models import params as P
 from repro.serve import InferenceServer
 
 PLEN, GEN, SLOTS, SEG = 8, 7, 4, 2
+TOK_POS = 2 * SLOTS * np.dtype(np.int32).itemsize  # a segment's tok + pos
 # Spans measured in place, mirrored as TraceAnnotations.
 ANNOTATED = {"dep_wait", "upload", "write_back", "merge", "harvest", "idle",
              "form_group"}
@@ -43,7 +44,8 @@ def model():
 def serve_one_wave(model, group):
     """SLOTS requests boarded as one wave (the batching wait outlasts the
     submits), each decoding GEN tokens: one join, then segments with no
-    join between them.  Returns the server's whole-cache input bytes."""
+    join between them.  Returns the segment Program's input bytes: the
+    token and position buffers plus the cache."""
     cfg, api, params = model
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, cfg.vocab, PLEN).astype(np.int32)
@@ -53,9 +55,9 @@ def serve_one_wave(model, group):
                          max_new_cap=GEN, max_wait_ms=5000.0) as srv:
         for h in [srv.submit(p, GEN) for p in prompts]:
             h.result(timeout=300)
-        leaves = srv.kernels.leaf_mirrors(SLOTS, srv._max_seq(PLEN))
-    tok_pos = 2 * SLOTS * np.dtype(np.int32).itemsize
-    return tok_pos + sum(b.nbytes for b in leaves)
+        leaves = srv.kernels.leaf_buffers(SLOTS, srv._max_seq(PLEN),
+                                          resident=True)
+    return TOK_POS + sum(b.nbytes for b in leaves)
 
 
 def spans(events, name, track=None):
@@ -92,8 +94,8 @@ def test_write_back_bytes_are_each_packages_outputs_padding_included():
     copied = {}
     execute = g.execute_chunk
 
-    def spy(program, off, size):
-        res = execute(program, off, size)
+    def spy(program, off, size, **kw):
+        res = execute(program, off, size, **kw)
         copied[off] = sum(r.nbytes for r in res)
         return res
 
@@ -116,13 +118,16 @@ def test_write_back_bytes_are_each_packages_outputs_padding_included():
 
 
 def test_first_segment_after_a_join_uploads_every_cache_leaf(traced_wave):
+    """After a join the first segment uploads what the join rewrote on
+    host — the token and position buffers — and reads every cache leaf on
+    the device, where the join wrote the joiners' rows."""
     events, cache_bytes = traced_wave
     segs = [e for e in spans(events, "upload", "group/spans")
             if e[7]["kernel"].startswith("decode_seg")]
     assert len(segs) >= 3
     first = segs[0][7]
-    assert first["bytes"] == cache_bytes
-    assert first["resident_bytes"] == 0
+    assert first["bytes"] == TOK_POS
+    assert first["resident_bytes"] == cache_bytes - TOK_POS
 
 
 def test_segment_with_no_join_uploads_nothing(traced_wave):
@@ -132,6 +137,19 @@ def test_segment_with_no_join_uploads_nothing(traced_wave):
     for e in segs[1:]:
         assert e[7]["bytes"] == 0
         assert e[7]["resident_bytes"] == cache_bytes
+
+
+def test_every_segment_write_back_keeps_the_cache(traced_wave):
+    """Each segment's ``write_back`` copies its tokens, token and position
+    buffers to host and keeps the whole cache on the device."""
+    events, cache_bytes = traced_wave
+    segs = spans(events, "segment", "batcher")
+    wbs = [e for e in spans(events, "write_back", "group/spans")
+           if any(s[1] <= e[1] and e[2] <= s[2] for s in segs)]
+    assert len(wbs) == len(segs) >= 3
+    for e in wbs:
+        assert e[7]["kept_bytes"] == cache_bytes - TOK_POS
+        assert e[7]["bytes"] == SLOTS * (SEG + 2) * 4
 
 
 # --------------------------------------------------------- span placement
@@ -197,9 +215,12 @@ def test_tracer_off_no_events_no_annotations_counters_kept(model,
     assert len(tracer()) == 0
     assert _Counting.names == []
     st = g.transfer_stats()
-    assert st["h2d_bytes"] >= cache_bytes
+    # The cache stays on the device: what crosses either way is the
+    # prompts, tokens and positions, below one copy of the cache.
+    assert 0 < st["h2d_bytes"] < cache_bytes
     assert st["resident_bytes"] >= cache_bytes
-    assert st["d2h_bytes"] >= cache_bytes
+    assert 0 < st["d2h_bytes"] < cache_bytes
+    assert st["kept_bytes"] >= cache_bytes
 
 
 def test_tracer_on_annotates_every_span_measured_in_place(model,
