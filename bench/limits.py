@@ -3,7 +3,8 @@
 of one cell over many seeds, in one process.
 
     python3 bench/limits.py --workload <cell> --seeds 1,2,3 --seconds <s> \\
-        [--control program_fp8_cache|reference_fp8] [--out <file.jsonl>]
+        [--control program_fp8_cache|reference_fp8] \\
+        [--fault no_exchange|altered] [--out <file.jsonl>]
 
 Each seed is a whole run (weights, warm-up, ramp, window, drain, check) as
 ``run.py`` makes it; one JSON line per seed gives the numbers compared (the
@@ -11,7 +12,9 @@ widest and the mean gap of the served tokens below the reference's best)
 and the check's log line, which with a control also has the float8
 reference's gaps at the same positions.  ``program_fp8_cache`` serves with
 the program's own float8 KV cache; ``reference_fp8`` compares the float8
-reference's first choices in the program's place.  Runs only on the chip.
+reference's first choices in the program's place.  ``--fault`` plants one
+of ``bench/faults.py``'s faults in the program for every run.  Runs only on
+the chip.
 """
 from __future__ import annotations
 
@@ -36,33 +39,38 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--control", default="",
                     choices=("", "reference_fp8", "program_fp8_cache"))
+    ap.add_argument("--fault", default="", choices=("", "no_exchange",
+                                                    "altered"))
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
     sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
-    from bench import harness
+    from bench import faults, harness
     from bench.run import chip, setup_jax
 
     cell = harness.resolve(args.workload)
-    device = chip(cell.chips)
-    if device is None:
+    devices = chip(cell.chips)
+    if devices is None:
         return 2
     setup_jax()
+    if args.fault:
+        faults.plant(args.fault)
     compile_log = harness.CompileLog()
     out = open(args.out, "a") if args.out else None
     try:
         for seed in (int(s) for s in args.seeds.split(",")):
             log = io.StringIO()
             with contextlib.redirect_stdout(log):
-                line = harness.run(cell, seed, args.seconds, False, device,
+                line = harness.run(cell, seed, args.seconds, False, devices,
                                    time.monotonic(), compile_log=compile_log,
                                    control=args.control,
                                    checks_out=io.StringIO())
             found = {}
             for text in log.getvalue().splitlines():
-                if text.startswith("check:"):
-                    found["check"] = text
+                for key in ("check", "slot migrations", "members at window"):
+                    if text.startswith(key):
+                        found[key] = text
             row = json.dumps({"workload": cell.name, "seed": seed,
-                              "control": args.control,
+                              "control": args.control, "fault": args.fault,
                               "correct": line["correct"],
                               "failed": line["failed"],
                               "checks": line["checks"], **found})

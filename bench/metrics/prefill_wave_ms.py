@@ -1,5 +1,6 @@
 """Median duration of the batcher's ``prefill_wave`` spans that start in
-the window: one whole-prompt prefill Program, submit to completion."""
+the window: one whole-prompt prefill Program, from the run's start to its
+end (service time; the wait before the start is not in it)."""
 from bench.window import span_durations
 from bench.stats import quantile
 

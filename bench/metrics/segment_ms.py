@@ -1,6 +1,8 @@
 """Median duration of the batcher's ``segment`` spans that start in the
-window: one decode segment Program (seg_len steps), submit to completion,
-write-back to host included."""
+window: one decode segment Program (seg_len steps), from the run's start to
+its end, so service time (the wait before the start is ``segment_wait_ms``).
+The write-back that ends the run copies the tokens and positions to host;
+the KV cache stays on the device."""
 from bench.window import span_durations
 from bench.stats import quantile
 

@@ -48,8 +48,8 @@ def setup_jax() -> None:
 
 
 def chip(chips: int):
-    """The first accelerator, or None (said on stderr) when JAX finds none
-    or fewer than ``chips``."""
+    """The first ``chips`` accelerators, or None (said on stderr) when JAX
+    finds none or fewer than ``chips``."""
     import jax
 
     devices = jax.devices()
@@ -61,7 +61,7 @@ def chip(chips: int):
         print(f"the cell needs {chips} chips, JAX finds {len(devices)}",
               file=sys.stderr)
         return None
-    return devices[0]
+    return devices[:chips]
 
 
 def main(argv=None) -> int:
@@ -70,12 +70,12 @@ def main(argv=None) -> int:
     from bench import harness
 
     cell = harness.resolve(args.workload)
-    device = chip(cell.chips)
-    if device is None:
+    devices = chip(cell.chips)
+    if devices is None:
         return 2
     setup_jax()
     line = harness.run(cell, args.seed, args.seconds, bool(args.trace),
-                       device, T_PROCESS, compile_log=harness.CompileLog())
+                       devices, T_PROCESS, compile_log=harness.CompileLog())
     print(json.dumps(line), flush=True)
     return 0
 

@@ -19,6 +19,7 @@ import time
 T_PROCESS = time.monotonic()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -75,12 +76,12 @@ def main(argv=None) -> int:
     cell = harness.resolve(args.workload)
     if cell.traffic["loop"] != "open":
         raise SystemExit("a sweep needs an open-loop cell")
-    device = chip(cell.chips)
-    if device is None:
+    devices = chip(cell.chips)
+    if devices is None:
         return 2
     setup_jax()
     compile_log = harness.CompileLog()
-    sess = harness.Session(cell, args.seed, device)
+    sess = harness.Session(cell, args.seed, devices)
     sess.warm_up()
     harness.log(f"ready after {time.monotonic() - T_PROCESS:.1f} s")
 
@@ -90,11 +91,11 @@ def main(argv=None) -> int:
             traffic = dict(cell.traffic, rate_rps=rate)
             plan = loadgen.make_plan(traffic, args.seed, args.seconds,
                                      cell.config["vocab_size"])
-            sess.cell = harness.Cell(cell.name, cell.chips, cell.config,
-                                     traffic, cell.end_to_end, cell.per_layer)
+            sess.cell = dataclasses.replace(cell, traffic=traffic)
             load = harness.drive(sess, plan, args.seconds,
                                  compile_log=compile_log)
-            ctx = harness.context(sess.cell, load, 0.0, device.device_kind)
+            ctx = harness.context(sess.cell, load, 0.0,
+                                  devices[0].device_kind)
             row = json.dumps(summary(rate, load, ctx, harness.reader))
             print(row, flush=True)
             if out:

@@ -1,13 +1,15 @@
-"""Useful operations from shapes, against parameter counts, and the table
-of peaks."""
+"""Useful operations from shapes (the dense architecture module's), against
+parameter counts, and the table of peaks."""
 import json
 import math
 import os
 
 import pytest
 
-from bench import flops, weights
+from bench import archs, flops
 from bench.tests.util import BENCH
+
+dense = archs.load({"program": {"bench_arch": "dense"}})
 
 
 def config(name):
@@ -18,7 +20,7 @@ def config(name):
 def matmul_params(c):
     """Every drawn weight matrix but the embedding (a gather, not a product)."""
     n = 0
-    for name, (shape, kind, _) in weights.layout(c).items():
+    for name, (shape, kind, _) in dense.layout(c).items():
         if kind != "normal" or name == "embed" or name.endswith(("bq", "bk", "bv")):
             continue
         size = math.prod(shape)
@@ -34,25 +36,25 @@ def test_decode_is_two_per_matmul_param_plus_attention(name):
     hd = c["hidden_size"] // h
     for ctx in (1, 300, 1024):
         want = 2 * mm + 4 * c["num_hidden_layers"] * h * hd * ctx
-        assert flops.decode_flops(c, ctx) == want
+        assert dense.decode_flops(c, ctx) == want
 
 
 def test_published_sizes():
     # qwen1.5-4b: 3.95 B parameters, 2.0 B of them in products... per token
     # about 7.9 GFLOP; internlm2-20b at 12 layers about 10.5 GFLOP.
-    assert flops.decode_flops(config("qwen15-4b"), 1) == pytest.approx(
+    assert dense.decode_flops(config("qwen15-4b"), 1) == pytest.approx(
         7.1e9, rel=0.05)
-    assert flops.decode_flops(config("internlm2-20b-s12"), 1) == \
+    assert dense.decode_flops(config("internlm2-20b-s12"), 1) == \
         pytest.approx(10.5e9, rel=0.05)
 
 
 def test_prefill_counts_the_head_once_and_causal_attention():
     c = config("internlm2-20b-s12")
     p = 100
-    layers = c["num_hidden_layers"] * flops.layer_matmul_params(c)
+    layers = c["num_hidden_layers"] * dense.layer_matmul_params(c)
     head = c["hidden_size"] * c["vocab_size"]
-    attn = sum(flops.attention_flops(c, k) for k in range(1, p + 1))
-    assert flops.prefill_flops(c, p) == 2 * layers * p + 2 * head + attn
+    attn = sum(dense.attention_flops(c, k) for k in range(1, p + 1))
+    assert dense.prefill_flops(c, p) == 2 * layers * p + 2 * head + attn
 
 
 def test_peaks_known_and_unknown():

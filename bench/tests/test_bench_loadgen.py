@@ -25,7 +25,8 @@ def plan(name, seed, seconds=51.0):
 
 
 @pytest.mark.parametrize("name", ["qwen15-4b.chat", "internlm2-20b-s12.decode",
-                                  "tiny.chat", "tiny.decode"])
+                                  "qwen15-4b.chat-x4", "tiny.chat",
+                                  "tiny.decode", "tiny.chat-x4"])
 def test_same_seed_same_requests(name):
     a, b = plan(name, SEED), plan(name, SEED)
     assert len(a.requests) == len(b.requests)
@@ -34,7 +35,8 @@ def test_same_seed_same_requests(name):
         assert (x.gen, x.at, x.in_window) == (y.gen, y.at, y.in_window)
 
 
-@pytest.mark.parametrize("name", ["qwen15-4b.chat", "internlm2-20b-s12.decode"])
+@pytest.mark.parametrize("name", ["qwen15-4b.chat", "internlm2-20b-s12.decode",
+                                  "qwen15-4b.chat-x4"])
 def test_other_seed_other_tokens_same_work(name):
     a, b = plan(name, SEED), plan(name, SEED + 1)
     assert [(r.gen, len(r.prompt), r.at) for r in a.requests] == \

@@ -72,3 +72,17 @@ def test_occupancy_and_device_idle():
     assert read("device_idle_share", c) is None
     c.device = {"idle_share": 0.875}
     assert read("device_idle_share", c) == pytest.approx(87.5)
+
+
+def test_member_balance():
+    c = ctx([])
+    assert read("member_balance", c) is None  # no trace
+    c.device = {"busy_s_per_plane": {"/device:TPU:0": 2.0}}
+    assert read("member_balance", c) is None  # one chip: nothing to balance
+    c.device = {"busy_s_per_plane": {"/device:TPU:0": 1.5,
+                                     "/device:TPU:1": 1.5}}
+    assert read("member_balance", c) == pytest.approx(100.0)
+    c.device = {"busy_s_per_plane": {f"/device:TPU:{i}": b
+                                     for i, b in enumerate((1.0, 2.0, 3.0,
+                                                            2.0))}}
+    assert read("member_balance", c) == pytest.approx(100.0 * 2.0 / 3.0)

@@ -13,6 +13,10 @@ if os.path.join(REPO, "src") not in sys.path:  # the program under test
     sys.path.insert(0, os.path.join(REPO, "src"))
 
 
+# The tiny cells and their chips: tiny.chat-x4 runs four members.
+CELLS = {"tiny.chat": 1, "tiny.decode": 1, "tiny.chat-x4": 4}
+
+
 def tiny_root(tmp: str) -> str:
     """``tmp`` made into a root: a copy of ``bench/`` with the tiny
     configuration and traffic files, and a BENCHMARK.json naming them."""
@@ -20,7 +24,7 @@ def tiny_root(tmp: str) -> str:
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
         "tests", "__pycache__"))
     shutil.copy(os.path.join(DATA, "tiny.json"), os.path.join(bench, "configs"))
-    for t in ("tiny.chat", "tiny.decode"):
+    for t in CELLS:
         shutil.copy(os.path.join(DATA, t + ".json"),
                     os.path.join(bench, "traffic"))
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
@@ -29,8 +33,8 @@ def tiny_root(tmp: str) -> str:
                         "file": "bench/configs/tiny.json", "reduced": [],
                         "why": "tests"}]
     spec["workloads"] = [
-        {"name": t, "config": "tiny", "traffic": t, "chips": 1, "why": "tests"}
-        for t in ("tiny.chat", "tiny.decode")]
+        {"name": t, "config": "tiny", "traffic": t, "chips": chips,
+         "why": "tests"} for t, chips in CELLS.items()]
     # Every metric in both tiny cells, but mfu: the CPU has no peak.
     spec["per_layer"] = [m for m in spec["per_layer"] if m["name"] != "mfu"]
     for m in spec["end_to_end"] + spec["per_layer"]:

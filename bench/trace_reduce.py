@@ -112,29 +112,38 @@ def name_gap(g: Tuple, spans: Sequence[Tuple]) -> str:
 
 def reduce(pd, lo_ns: float, hi_ns: float, spans: Sequence[Tuple] = (),
            plane_prefix: str = DEVICE_PLANE, line_name: str = OPS_LINE,
-           top: int = TOP) -> dict:
-    """Busy seconds (union of operations, averaged over the device planes),
-    the window's length, the idle share, the ``top`` operations by total
-    time and the ``top`` longest idle gaps, over [lo_ns, hi_ns)."""
+           top: int = TOP, names: Optional[Sequence[str]] = None) -> dict:
+    """Busy seconds (union of operations, averaged over the device planes,
+    and each plane's own under ``busy_s_per_plane``), the window's length,
+    the idle share, the ``top`` operations by total time and the ``top``
+    longest idle gaps, over [lo_ns, hi_ns).  ``names``: the device planes
+    to count (the chips a run used), each whether or not an operation ran
+    on it (a chip that ran nothing has no plane in the trace); by default
+    every plane with operations."""
     planes = device_ops(pd, plane_prefix, line_name)
     if not planes:
         raise ValueError(f"no {line_name!r} line on a {plane_prefix!r} plane")
+    if names is not None:
+        if not set(planes) & set(names):
+            raise ValueError(f"no operation on any of {list(names)}")
+        planes = {n: planes.get(n, []) for n in names}
     window = (hi_ns - lo_ns) / 1e9
-    busy_total, per_op, all_gaps = 0.0, defaultdict(float), []
-    for ops in planes.values():
+    per_plane, per_op, all_gaps = {}, defaultdict(float), []
+    for plane, ops in planes.items():
         busy = union(ops, lo_ns, hi_ns)
-        busy_total += sum(e - s for s, e in busy) / 1e9
+        per_plane[plane] = sum(e - s for s, e in busy) / 1e9
         for s, e, name in ops:
             d = min(e, hi_ns) - max(s, lo_ns)
             if d > 0:
                 per_op[name] += d / 1e9
         all_gaps.extend(gaps(busy, lo_ns, hi_ns))
-    busy_s = busy_total / len(planes)
+    busy_s = sum(per_plane.values()) / len(planes)
     all_gaps.sort(key=lambda g: g[0] - g[1])
     return {
         "busy_s": busy_s,
         "window_s": window,
         "idle_share": 1.0 - busy_s / window,
+        "busy_s_per_plane": per_plane,
         "device_ops": sorted(([n, t] for n, t in per_op.items()),
                              key=lambda x: -x[1])[:top],
         "idle_gaps": [[name_gap(g, spans), (g[1] - g[0]) / 1e9]
@@ -157,10 +166,12 @@ def host_spans(events: Sequence[tuple], to_ns) -> List[Tuple]:
 
 
 def reduce_dir(d: str, anchors: dict, events: Sequence[tuple] = (),
-               platform: str = "tpu") -> dict:
+               platform: str = "tpu",
+               device_ids: Optional[Sequence[int]] = None) -> dict:
     """Reduce the trace under ``d`` over the window between the two anchor
     annotations, whose host-clock times are ``anchors``; ``events`` are the
-    Tracer's events on that host clock."""
+    Tracer's events on that host clock.  ``device_ids``: the TPU chips the
+    run used, each counted (idle or not) in the busy time and idle share."""
     pd = load(find_trace(d))
     lo, hi = annotation(pd, START), annotation(pd, END)
     if lo is None or hi is None:
@@ -170,4 +181,8 @@ def reduce_dir(d: str, anchors: dict, events: Sequence[tuple] = (),
     def to_ns(t: float) -> float:
         return lo + (t - t_lo) * 1e9
 
-    return reduce(pd, lo, hi, host_spans(events, to_ns), *OPS[platform])
+    names = None
+    if platform == "tpu" and device_ids is not None:
+        names = [f"{DEVICE_PLANE}:{i}" for i in device_ids]
+    return reduce(pd, lo, hi, host_spans(events, to_ns), *OPS[platform],
+                  names=names)

@@ -5,9 +5,10 @@ for a stacked layer leaf, the layer's index.  So the whole tree comes out of
 one jitted call in the served dtype, and the reference can draw one layer at
 a time again, bit for bit, without anything the program has held.
 
-The tree has the layout the program's dense decoder takes (``embed``,
-``final_norm``, ``lm_head``, and ``layers`` stacked on a leading axis); the
-shapes come from the configuration file alone.
+The tree's layout (every leaf's name, shape and spread) is the ``layout`` of
+the configuration's architecture module, ``archs/<name>.py``: the top-level
+``embed``, ``final_norm`` and ``lm_head``, and ``layers/...`` leaves stacked on
+a leading axis.
 """
 from __future__ import annotations
 
@@ -27,33 +28,6 @@ def seed_words(seed: int) -> np.ndarray:
     """A seed of up to 64 bits as the two 32-bit words of a key."""
     seed = int(seed) % (1 << 64)
     return np.array([seed >> 32, seed & 0xFFFFFFFF], np.uint32)
-
-
-def layout(c: dict) -> dict:
-    """name -> (shape, kind, std) of every leaf; layer leaves are named
-    ``layers/...`` and drawn per layer (shape without the layer axis)."""
-    d, f, v = c["hidden_size"], c["intermediate_size"], c["vocab_size"]
-    h, kv = c["num_attention_heads"], c["num_key_value_heads"]
-    hd = c.get("head_dim") or d // h
-    out = {
-        "embed": ((v, d), "normal", EMBED_STD),
-        "final_norm": ((d,), "norm", NORM_STD),
-        "lm_head": ((d, v), "normal", d ** -0.5),
-        "layers/attn/wq": ((d, h, hd), "normal", d ** -0.5),
-        "layers/attn/wk": ((d, kv, hd), "normal", d ** -0.5),
-        "layers/attn/wv": ((d, kv, hd), "normal", d ** -0.5),
-        "layers/attn/wo": ((h, hd, d), "normal", (h * hd) ** -0.5),
-        "layers/mlp/w_gate": ((d, f), "normal", d ** -0.5),
-        "layers/mlp/w_up": ((d, f), "normal", d ** -0.5),
-        "layers/mlp/w_down": ((f, d), "normal", f ** -0.5),
-        "layers/norm1": ((d,), "norm", NORM_STD),
-        "layers/norm2": ((d,), "norm", NORM_STD),
-    }
-    if c["program"]["qkv_bias"]:
-        out["layers/attn/bq"] = ((h, hd), "normal", BIAS_STD)
-        out["layers/attn/bk"] = ((kv, hd), "normal", BIAS_STD)
-        out["layers/attn/bv"] = ((kv, hd), "normal", BIAS_STD)
-    return out
 
 
 def _leaf_key(words, name: str):
@@ -97,13 +71,14 @@ def _init_fn(items: tuple, n_layers: int, dtype: str):
     return jax.jit(init)
 
 
-def _items(c: dict) -> tuple:
+def _items(c: dict, layout) -> tuple:
+    """The leaves of ``layout(c)``."""
     return tuple((n, s, k, sd) for n, (s, k, sd) in layout(c).items())
 
 
-def make_params(c: dict, seed: int, dtype: str):
+def make_params(c: dict, seed: int, dtype: str, layout):
     """Every weight in ``dtype``, on the default device, in one jitted call."""
-    fn = _init_fn(_items(c), c["num_hidden_layers"], dtype)
+    fn = _init_fn(_items(c, layout), c["num_hidden_layers"], dtype)
     return fn(jnp.asarray(seed_words(seed)))
 
 
@@ -120,9 +95,9 @@ def _layer_fn(items: tuple, dtype: str):
     return jax.jit(one)
 
 
-def layer_params(c: dict, seed: int, dtype: str, i: int):
+def layer_params(c: dict, seed: int, dtype: str, i: int, layout):
     """Layer ``i``'s weights exactly as ``make_params`` drew them."""
-    fn = _layer_fn(_items(c), dtype)
+    fn = _layer_fn(_items(c, layout), dtype)
     return fn(jnp.asarray(seed_words(seed)), jnp.int32(i))
 
 
@@ -136,6 +111,6 @@ def _top_fn(items: tuple, dtype: str):
     return jax.jit(top)
 
 
-def top_params(c: dict, seed: int, dtype: str) -> dict:
+def top_params(c: dict, seed: int, dtype: str, layout) -> dict:
     """``embed``, ``final_norm`` and ``lm_head`` as ``make_params`` drew them."""
-    return _top_fn(_items(c), dtype)(jnp.asarray(seed_words(seed)))
+    return _top_fn(_items(c, layout), dtype)(jnp.asarray(seed_words(seed)))
