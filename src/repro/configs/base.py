@@ -8,7 +8,7 @@ from typing import Optional
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # dense | moe | ssm | hybrid | audio | vlm
+    family: str  # dense | moe | mla_moe | ssm | hybrid | audio | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -76,6 +76,42 @@ class ModelConfig:
         if self.family == "hybrid" and self.window > 0:
             return True
         return False
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaMoeConfig(ModelConfig):
+    """DeepSeek-V3's block (Kimi-K2 reuses it): latent attention (MLA) over
+    sparse experts, after one leading dense layer.
+
+    Routing is fixed to the published ``noaux_tc`` form: sigmoid scores over
+    all ``n_experts``, the top ``top_k`` of score + a learned bias, weights
+    the chosen scores normalised to sum 1 and scaled by ``route_scale``
+    (one expert group).  ``d_ff`` is the dense layers' width; ``moe_d_ff``
+    the routed and the shared experts'.  A chip of an expert-parallel
+    deployment holds ``experts_held`` experts from ``expert_offset`` on (0:
+    all); the router keeps its ``n_experts`` outputs."""
+
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0  # the cached latent's width
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0  # rope dims of q/k; the k part is shared by heads
+    v_head_dim: int = 0
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    route_scale: float = 1.0
+    experts_held: int = 0
+    expert_offset: int = 0
+    # YaRN rope scaling (factor 0 = plain rope at rope_theta).
+    yarn_factor: float = 0.0
+    yarn_orig_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)

@@ -6,7 +6,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import MlaMoeConfig, ModelConfig
 
 
 def reduced(cfg: ModelConfig) -> ModelConfig:
@@ -15,7 +15,7 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     n_heads = 4
     n_kv = max(1, n_heads // kv_ratio) if kv_ratio else 0
     n_layers = max(2, len(cfg.block_pattern)) if cfg.block_pattern else 2
-    return dataclasses.replace(
+    out = dataclasses.replace(
         cfg,
         name=cfg.name + "-smoke",
         n_layers=n_layers,
@@ -39,3 +39,12 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         remat="none",
         zero1=False,
     )
+    if isinstance(cfg, MlaMoeConfig):
+        # One dense layer and one MoE layer of 8 experts, all held; YaRN
+        # keeps its factor over a short original context.
+        out = dataclasses.replace(
+            out, n_experts=8, head_dim=24, q_lora_rank=32, kv_lora_rank=32,
+            qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, moe_d_ff=32,
+            experts_held=0, expert_offset=0,
+            yarn_orig_max_pos=16 if cfg.yarn_factor else 0)
+    return out

@@ -10,6 +10,10 @@
         -> (logits, cache)   # mixed-phase chunked prefill; None when the
                              # family has no chunked path (validate_chunked
                              # gates serving accordingly)
+    api.decode_counts(params, token, pos, cfg, cache)
+        -> (logits, cache, counts)  # decode that also reports per-slot
+                             # counters (B, 2) int32, serve.batcher.COUNTERS;
+                             # None when the family has none
 """
 from __future__ import annotations
 
@@ -23,6 +27,7 @@ class ModelAPI(NamedTuple):
     prefill: Callable
     decode: Callable
     prefill_chunk: Optional[Callable] = None
+    decode_counts: Optional[Callable] = None
 
 
 def get_model(cfg) -> ModelAPI:
@@ -32,6 +37,11 @@ def get_model(cfg) -> ModelAPI:
         chunk = T.prefill_chunk if cfg.family != "ssm" else None
         return ModelAPI(T.param_spec, T.cache_spec, T.forward_train, T.prefill,
                         T.decode, chunk)
+    if cfg.family == "mla_moe":
+        from repro.models import mla_moe as M
+
+        return ModelAPI(M.param_spec, M.cache_spec, M.forward_train, M.prefill,
+                        M.decode, decode_counts=M.decode_counts)
     if cfg.family == "hybrid":
         from repro.models import rglru as R
 

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts block (kimi-k2, arctic).
+"""Mixture-of-Experts blocks: arctic's capacity-dropping training block, and
+the held-expert layer of the ``mla_moe`` family (kimi-k2, :func:`held_moe`).
 
 Top-k routing with capacity-bounded sort-free scatter dispatch:
 tokens are scattered into an (E, C, d) buffer (sharded E→model axis,
@@ -209,3 +210,76 @@ def moe_block_apply(p, x, positions, cfg, *, mode, cache=None, pos=None, prefix_
     if mode == "train":
         new_cache = aux_load_balance_loss(h.reshape(b * s, d), p["router"], cfg)
     return shard(x, "batch", None, None), new_cache
+
+
+# ------------------------------------------------------- held-expert layer
+#
+# DeepSeek-V3's expert layer (Kimi-K2 reuses it) as one chip of an expert-
+# parallel deployment holds it: the router scores all ``n_experts``; this
+# chip computes the part of the result that its ``n_held`` experts give for
+# the tokens routed to them, plus the shared experts.  What the absent
+# experts would add is left out, as their chips would add it.  No token is
+# dropped: every (token, expert) pair of the top-k gets a row of the grouped
+# matmuls, those on held experts first, sorted by expert.
+
+
+def held_moe_spec(cfg) -> dict:
+    d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.n_held
+    fs = f * cfg.n_shared_experts
+    return {
+        "router": Spec((d, cfg.n_experts), scale=d ** -0.5),
+        "router_bias": Spec((cfg.n_experts,), init="zeros"),
+        "experts": {"w_gate": Spec((e, d, f), scale=d ** -0.5),
+                    "w_up": Spec((e, d, f), scale=d ** -0.5),
+                    "w_down": Spec((e, f, d), scale=f ** -0.5)},
+        "shared": {"w_gate": Spec((d, fs), scale=d ** -0.5),
+                   "w_up": Spec((d, fs), scale=d ** -0.5),
+                   "w_down": Spec((fs, d), scale=fs ** -0.5)},
+    }
+
+
+def route(x, router, bias, cfg):
+    """Sigmoid scores of all experts (float32); the top-k of score + bias;
+    weights: the chosen scores normalised to sum 1, times route_scale.
+    Returns (ids (T, K), weights (T, K) float32)."""
+    s = jax.nn.sigmoid(jnp.einsum("td,de->te", x, router,
+                                  preferred_element_type=jnp.float32))
+    _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), cfg.top_k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    return ids, w / jnp.sum(w, axis=-1, keepdims=True) * cfg.route_scale
+
+
+def held_moe(x, p, cfg, layer=0):
+    """x: (T, d) -> (y (T, d), held (T,) int32: the token's top-k experts
+    that are held here).  The grouped matmuls take T * top_k rows (the
+    dropless bound; ``jax.lax.ragged_dot`` skips the rows past the held
+    groups) and the shared experts run on every token.
+
+    ``p["experts"]`` may hold every expert layer's experts, stacked on a
+    leading axis, with ``layer`` this layer's index: the grouped matmuls then
+    take the whole stack as ``L * n_held`` groups, the other layers' empty.
+    A layer's slice of the stack would be a copy (a kernel's operand is a
+    buffer of its own), read and written again every step."""
+    t, d = x.shape
+    k, e = cfg.top_k, cfg.n_held
+    ids, w = route(x, p["router"], p["router_bias"], cfg)
+    local = ids - cfg.expert_offset
+    held = (local >= 0) & (local < e)  # (T, K)
+    group = jnp.where(held, local, e).reshape(-1)  # e: not held, sorted last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=e + 1)[:e].astype(jnp.int32)
+    ex = {n: a.reshape((-1,) + a.shape[-2:]) for n, a in p["experts"].items()}
+    sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros(ex["w_gate"].shape[0], jnp.int32), sizes, (layer * e,))
+    xs = x[order // k]  # (T*K, d), held rows first, by expert
+    h = (jax.nn.silu(jax.lax.ragged_dot(xs, ex["w_gate"], sizes))
+         * jax.lax.ragged_dot(xs, ex["w_up"], sizes))
+    ys = jax.lax.ragged_dot(h, ex["w_down"], sizes)
+    # Rows past the held groups are not computed: zero them before weighting.
+    ys = jnp.where((jnp.arange(t * k) < jnp.sum(sizes))[:, None], ys, 0)
+    ys = ys[jnp.argsort(order)].reshape(t, k, d)  # back to token order
+    y = jnp.einsum("tkd,tk->td", ys, jnp.where(held, w, 0.0).astype(ys.dtype),
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+    sh = p["shared"]
+    y = y + L.swiglu(x, sh["w_gate"], sh["w_up"], sh["w_down"])
+    return y, jnp.sum(held, axis=-1).astype(jnp.int32)

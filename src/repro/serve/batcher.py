@@ -77,6 +77,12 @@ from repro.serve.step import (
 )
 
 
+# The per-slot counters of a counting family's decode step
+# (``api.decode_counts``), in order: (token, held expert) pairs routed, and
+# rows of the expert matmuls.
+COUNTERS = ("expert_routed", "expert_rows")
+
+
 def chunks_for(bucket: int, chunk_len: int, start: int = 0) -> int:
     """Mixed-phase segments a prompt needs before its first token: the
     prefill cursor advances ``chunk_len`` positions per segment from
@@ -147,6 +153,9 @@ class ModelKernels:
         self.treedef = jax.tree_util.tree_structure(self.bax)
         self._seg_fns: dict = {}
         self._prefill_fns: dict = {}
+        # A family that counts (``api.decode_counts``) adds a per-slot
+        # counter output to plain decode segments.
+        self.counted = api.decode_counts is not None
         self.draft = draft
         if draft is not None:
             from repro.models import get_model
@@ -200,6 +209,12 @@ class ModelKernels:
         return _slot_buffers(self._draft_leaf_specs(max_seq), self.dbax_leaves,
                              self.draft.cfg.compute_dtype, n_slots, resident)
 
+    def row_bytes(self) -> int:
+        """Cache bytes one slot keeps per position, over every leaf."""
+        specs = self._leaf_specs(8)
+        return sum(int(np.prod(s.shape)) * np.dtype(s.dtype or
+                   self.cfg.compute_dtype).itemsize for s in specs) // 8
+
     def leaf_neg_init(self, max_seq: int) -> List[bool]:
         """Which cache leaves record positions (init ``neg_ones``) — the
         leaves a paged pool must reset to −1 when a block is reallocated."""
@@ -242,11 +257,15 @@ class ModelKernels:
         The decode path is natively batched over vector positions, so the
         slot-leading mirror layout is converted to the model's native batch
         axes ONCE per segment (and back once), outside the scan — no
-        per-token tree churn, no vmap expand/squeeze of every cache leaf."""
+        per-token tree churn, no vmap expand/squeeze of every cache leaf.
+        A counting family (:attr:`counted`) also returns its per-slot
+        counters summed over the segment's steps, after the cache leaves."""
         fn = self._seg_fns.get(seg_len)
         if fn is not None:
             return fn
-        decode = make_decode_step(self.cfg, self.api)
+        counted = self.counted
+        decode = (make_decode_step(self.cfg, self.api, counts=True) if counted
+                  else make_decode_step(self.cfg, self.api))
         treedef, bax = self.treedef, self.bax
         tu = jax.tree_util
 
@@ -256,16 +275,22 @@ class ModelKernels:
             cache = tu.tree_map(lambda x, a: jnp.moveaxis(x, 0, a), cache, bax)
 
             def body(carry, _):
-                tok, pos, cache = carry
-                ntok, cache = decode(params, cache, tok, pos[:, 0])
-                return (ntok, pos + 1, cache), ntok[:, 0]
+                tok, pos, cache, n = carry
+                if counted:
+                    ntok, cache, c = decode(params, cache, tok, pos[:, 0])
+                    n = n + c
+                else:
+                    ntok, cache = decode(params, cache, tok, pos[:, 0])
+                return (ntok, pos + 1, cache, n), ntok[:, 0]
 
-            (tok, pos, cache), toks = jax.lax.scan(
-                body, (tok, pos, cache), None, length=seg_len
+            n0 = jnp.zeros((tok.shape[0], len(COUNTERS)),
+                           jnp.int32) if counted else None
+            (tok, pos, cache, n), toks = jax.lax.scan(
+                body, (tok, pos, cache, n0), None, length=seg_len
             )
             cache = tu.tree_map(lambda x, a: jnp.moveaxis(x, a, 0), cache, bax)
-            return (jnp.swapaxes(toks, 0, 1), tok, pos,
-                    *tu.tree_leaves(cache))
+            out = (jnp.swapaxes(toks, 0, 1), tok, pos, *tu.tree_leaves(cache))
+            return out + (n,) if counted else out
 
         self._seg_fns[seg_len] = seg
         return seg
@@ -816,6 +841,7 @@ class BatchGroup:
         # device.  None: runs split across groups, or a kernel-only group
         # (no runtime), keep host mirrors of the cache.
         self.home = self._home_group()
+        self._row_bytes = kernels.row_bytes() if kernels.counted else 0
         with tracer().span("form_group", track="batcher", bucket=bucket):
             self._build_segment_program()
         self.seg_handle = None
@@ -899,6 +925,8 @@ class BatchGroup:
         prog.out(toks_seg).out(np.zeros_like(tok)).out(np.zeros_like(pos))
         for b in leaves:
             prog.out(_blank(b))
+        if kernels.counted:  # the per-slot counters, after the leaves
+            prog.out(np.zeros((n_slots, len(COUNTERS)), np.int32))
         prog.kernel(kernels.segment_kernel(seg_len), f"decode_seg{seg_len}")
         # Donate the cache-leaf inputs (mirroring make_generate's
         # donate_argnums=(1,)): each segment's jitted kernel updates the KV
@@ -1228,6 +1256,7 @@ class BatchGroup:
         n_active = 0
         finished = []
         emitted = drafted = accepted = chunk_tokens = delivered = 0
+        rows_read = 0  # cache rows the active slots' steps attended
         tr = tracer()
         traced = tr.enabled
         for slot, req in self.active():
@@ -1281,6 +1310,12 @@ class BatchGroup:
                                      accepted=a)
             else:
                 take = toks_seg[slot, : min(self.seg_len, need)]
+                if self._row_bytes:
+                    # Step j of the segment sits at position pos0 + j and
+                    # attends rows 0..pos0 + j.
+                    pos0 = self.bucket + len(req.tokens) - 1
+                    rows_read += self.seg_len * (pos0 + 1) + \
+                        self.seg_len * (self.seg_len - 1) // 2
                 if traced:
                     tr.async_instant("decode_segment", req.seq, slot=slot,
                                      tokens=int(len(take)))
@@ -1290,8 +1325,15 @@ class BatchGroup:
                 finished.append(req)
                 self.release_slot(slot)
         self.tokens_written += emitted if self.spec_k else n_active * self.seg_len
+        counts = {}
+        if self.kernels.counted and not (self.spec_k or self.chunk_len):
+            # Over every slot, as the step computed them; the latent bytes
+            # at the active slots' real lengths.
+            n = self.prog._outs[-1].sum(axis=0)
+            counts = {k: int(v) for k, v in zip(COUNTERS, n)}
+            counts["latent_bytes"] = rows_read * self._row_bytes
         _trace_run(tr, "segment", h, bucket=self.bucket, n_active=n_active,
-                   finished=len(finished), chunk_tokens=chunk_tokens)
+                   finished=len(finished), chunk_tokens=chunk_tokens, **counts)
         if self.telemetry is not None and chunk_tokens:
             self.telemetry.count("chunk_tokens", chunk_tokens)
         res = {"n_active": n_active, "finished": finished, "seconds": seconds,

@@ -1036,6 +1036,9 @@ def validate_paged(cfg, groups, scheduler, spec: PagedSpec, *,
         )
     if cfg.seq_shard_cache:
         raise ValueError("paged serving is incompatible with seq_shard_cache")
+    if cfg.family == "mla_moe":
+        raise ValueError("paged serving has no latent-cache (MLA) path: "
+                         "serve mla_moe on contiguous slots")
     if cfg.kernel_impl in ("pallas", "pallas_interpret") and \
             cfg.decode_block != spec.block_len:
         raise ValueError(

@@ -71,15 +71,19 @@ def make_prefill_step(cfg, api):
     return prefill_step
 
 
-def make_decode_step(cfg, api):
+def make_decode_step(cfg, api, *, counts: bool = False):
     """``(params, cache, token, pos) -> (token, cache)``; ``pos`` is a
     scalar (uniform batch) or a (B,) per-slot position vector — the model's
-    decode path is natively batched over vector positions."""
+    decode path is natively batched over vector positions.  ``counts``:
+    ``-> (token, cache, counts)`` through ``api.decode_counts``."""
     def decode_step(params, cache, token, pos):
         params = cast_params_cached(params, cfg.compute_dtype)
-        logits, cache = api.decode(params, token, pos, cfg, cache)
+        if counts:
+            logits, cache, n = api.decode_counts(params, token, pos, cfg, cache)
+        else:
+            logits, cache = api.decode(params, token, pos, cfg, cache)
         next_tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
-        return next_tok, cache
+        return (next_tok, cache, n) if counts else (next_tok, cache)
 
     return decode_step
 

@@ -86,7 +86,7 @@ def test_full_configs_have_exact_dimensions():
         "qwen1.5-4b": (40, 2560, 20, 20, 6912, 151936),
         "internlm2-20b": (48, 6144, 48, 8, 16384, 92544),
         "paligemma-3b": (18, 2048, 8, 1, 16384, 257216),
-        "kimi-k2-1t-a32b": (61, 7168, 64, 8, 2048, 163840),
+        "kimi-k2-1t-a32b": (61, 7168, 64, 64, 18432, 163840),
         "arctic-480b": (35, 7168, 56, 8, 4864, 32000),
         "whisper-tiny": (4, 384, 6, 6, 1536, 51865),
         "falcon-mamba-7b": (64, 4096, 0, 0, 0, 65024),
@@ -99,6 +99,13 @@ def test_full_configs_have_exact_dimensions():
     # Family features.
     assert get_config("kimi-k2-1t-a32b").n_experts == 384
     assert get_config("kimi-k2-1t-a32b").top_k == 8
+    kimi = get_config("kimi-k2-1t-a32b")
+    assert (kimi.family, kimi.q_lora_rank, kimi.kv_lora_rank, kimi.qk_nope_dim,
+            kimi.qk_rope_dim, kimi.v_head_dim) == ("mla_moe", 1536, 512, 128,
+                                                   64, 128)
+    assert (kimi.moe_d_ff, kimi.n_shared_experts, kimi.route_scale,
+            kimi.rope_theta, kimi.yarn_factor) == (2048, 1, 2.827, 50000.0,
+                                                   32.0)
     assert get_config("arctic-480b").n_experts == 128
     assert get_config("arctic-480b").dense_residual
     assert get_config("falcon-mamba-7b").ssm_state == 16

@@ -61,7 +61,7 @@ from repro.models import params as P
 from repro.launch.mesh import make_mesh
 from repro.distributed import set_current_mesh
 
-cfg0 = reduced(get_config("kimi-k2-1t-a32b"))
+cfg0 = reduced(get_config("arctic-480b"))
 api = get_model(cfg0)
 params = P.materialize(api.param_spec(cfg0, 1), jax.random.PRNGKey(0), jnp.float32)
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg0.vocab)}

@@ -1,5 +1,6 @@
 """The serving kernels compile for a TPU v5e at qwen1.5-4b's published
-widths (bf16, 20 heads of 128, 40 layers).
+widths (bf16, 20 heads of 128, 40 layers), and the MLA decode kernel at
+Kimi-K2's (64 heads over a 512 + 64 latent row).
 
 Nothing runs: each test compiles for a described ``v5e:2x2`` topology, which
 raises what the chip's compiler would raise (block shapes that do not tile,
@@ -20,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.kernels.mla_decode import mla_decode
 from repro.models import get_model
 from repro.models.params import Spec, tree_map_specs
 from repro.serve.batcher import ModelKernels
@@ -88,6 +90,19 @@ def test_flash_decode_paged_compiles(one_chip, cfg):
             _sds(one_chip, (b, nmax), jnp.int32),
             _sds(one_chip, (b,), jnp.int32))
     compiled = jax.jit(lambda *a: flash_decode_paged(*a)).lower(*args).compile()
+    assert _has_kernel(compiled)
+
+
+def test_mla_decode_compiles(one_chip):
+    """16 slots over 3072 latent rows of one of 8 stacked layers, as the
+    kimi-k2-s9 cell decodes."""
+    b, s, h, r, p, layers = 16, 3072, 64, 512, 64, 8
+    args = (_sds(one_chip, (b, h, r), BF16), _sds(one_chip, (b, h, p), BF16),
+            _sds(one_chip, (b, layers, s, r), BF16),
+            _sds(one_chip, (b, layers, s, p), BF16),
+            _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (), jnp.int32))
+    compiled = jax.jit(lambda *a: mla_decode(*a, scale=0.13)
+                       ).lower(*args).compile()
     assert _has_kernel(compiled)
 
 
