@@ -193,7 +193,8 @@ class DeviceGroup:
 
     def _input_slice(self, program, host_buf, offset_wi: int, size_wi: int,
                      bucket: int, *, consume: bool = False,
-                     keep_resident: bool = False):
+                     keep_resident: bool = False,
+                     host_read: Optional[Callable] = None):
         """Device copy of one input's package slice, padded to the bucket.
 
         Cached per (buffer version, offset, bucket): iterative/serving reruns
@@ -205,12 +206,18 @@ class DeviceGroup:
         ``keep_resident`` (a run pinned to this group): a ``Resident`` input
         is read from its device value (:meth:`_resident_slice`).
 
+        ``host_read(host_buf)`` is called before the slice is read from host
+        or a cached device copy of it is donated (the runtime waits there
+        for a producer that has yet to copy it back to host).
+
         Returns (device array, bytes put on the device, bytes served from
         the cache or a ``Resident`` value)."""
         lo, hi = program.rows_of(host_buf, offset_wi, size_wi)
         need = int(program.buffer_ratio(host_buf) * bucket) - (hi - lo)
         if keep_resident and isinstance(host_buf, Resident):
             return self._resident_slice(host_buf, lo, hi, need, consume)
+        if consume and host_read is not None:
+            host_read(host_buf)
         # A buffer that is both input and output of the same Program
         # (in-place update) is uncacheable: under run-scoped write versions a
         # mid-run input slice would be keyed on the run's final version and
@@ -246,6 +253,8 @@ class DeviceGroup:
                     if not consume:
                         self._cache_put(key, dev, host_buf)
                     return dev, 0, dev.nbytes
+        if host_read is not None:
+            host_read(host_buf)
         b = host_buf[lo:hi]
         if need > 0:
             b = np.pad(np.asarray(b), [(0, need)] + [(0, 0)] * (b.ndim - 1))
@@ -335,7 +344,8 @@ class DeviceGroup:
         return True
 
     def execute_chunk(self, program, offset_wi: int, size_wi: int, *,
-                      keep_resident: bool = False):
+                      keep_resident: bool = False,
+                      host_read: Optional[Callable] = None):
         """Run one package; returns device arrays (async, not blocked).
 
         Inputs are padded to the bucket size; callers must trim outputs to
@@ -346,6 +356,7 @@ class DeviceGroup:
         ``keep_resident``: the run is pinned to this group).
         ``jax.device_put`` may return before its copy ends, so the span is
         the host's share of the copy; ``bytes`` is the whole of it.
+        ``host_read``: see :meth:`_input_slice`.
         """
         fn = self.compile_kernel(program)
         bucket = self._bucket(size_wi, program.lws)
@@ -357,7 +368,8 @@ class DeviceGroup:
                 dev, up, hit = self._input_slice(program, b, offset_wi,
                                                  size_wi, bucket,
                                                  consume=i in donated,
-                                                 keep_resident=keep_resident)
+                                                 keep_resident=keep_resident,
+                                                 host_read=host_read)
                 ins.append(dev)
                 h2d += up
                 resident += hit

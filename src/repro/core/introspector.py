@@ -46,11 +46,11 @@ class Introspector:
         self.counters: Dict[str, dict] = {}  # device -> transfer counters
         self._sink = sink
 
-    def start_run(self) -> None:
+    def start_run(self, t: Optional[float] = None) -> None:
+        """Stamp the run's start: now, or ``t`` (a run queued behind another
+        on its device starts when that one completes)."""
         with self._lock:
-            self.records = []
-            self.counters = {}
-            self.t_run_start = time.perf_counter()
+            self.t_run_start = time.perf_counter() if t is None else t
 
     def end_run(self) -> None:
         with self._lock:

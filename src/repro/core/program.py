@@ -85,9 +85,10 @@ class Resident:
 
     A run pinned to exactly one ``DeviceGroup`` (``RunHandle.on_one_group``)
     reads the value on that group's device and leaves its new output value
-    there: ``Runtime._write_back`` copies nothing to host for it, and it is
-    never an entry of the evictable transfer cache, so an eviction cannot
-    lose it.  A run split across several groups first brings it to host
+    there, at dispatch (``Runtime._hand_off``: a later run on the group may
+    take it before this one completes); nothing of it is copied to host,
+    and it is never an entry of the evictable transfer cache, so an
+    eviction cannot lose it.  A run split across several groups first brings it to host
     (:meth:`to_host`); for that run it is an ordinary host buffer, written
     back and handed off through the transfer cache like any other.
 
@@ -399,7 +400,8 @@ class Program:
         return out
 
     def write_outputs(self, offset_wi: int, size_wi: int, results: Sequence,
-                      *, bump: bool = True, keep_resident: bool = False) -> int:
+                      *, bump: bool = True, keep_resident: bool = False,
+                      outs: Optional[Sequence] = None) -> int:
         """Write one package's results back to the host output buffers.
 
         ``bump=True`` (the default, tier-1 semantics) re-versions each buffer
@@ -412,16 +414,21 @@ class Program:
         ``keep_resident`` skips the ``Resident`` outputs: a run pinned to one
         group leaves them on the device (``Resident.keep``).
 
+        ``outs``: the buffers to write (default: the Program's outputs now)
+        — the runtime passes the ones the run was dispatched with
+        (``RunHandle.outputs``), which an epilogue may since have swapped.
+
         Returns the bytes copied to host, bucket padding included: the whole
         result crosses before it is trimmed."""
         if not isinstance(results, (tuple, list)):
             results = (results,)
-        if len(results) != len(self._outs):
+        outs = self._outs if outs is None else outs
+        if len(results) != len(outs):
             raise ValueError(
-                f"kernel returned {len(results)} outputs, program has {len(self._outs)}"
+                f"kernel returned {len(results)} outputs, program has {len(outs)}"
             )
         nbytes = 0
-        for b, res in zip(self._outs, results):
+        for b, res in zip(outs, results):
             if keep_resident and isinstance(b, Resident):
                 continue
             lo, hi = self.rows_of(b, offset_wi, size_wi)

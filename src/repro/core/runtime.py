@@ -5,9 +5,12 @@ The paper's headline overhead result (≤2.8% vs. native OpenCL) relies on a
 kernel launches.  This module is that runtime for the JAX port:
 
 - ``GroupExecutor`` — one long-lived daemon thread per ``DeviceGroup``
-  draining a FIFO job queue, so repeated runs/steps never pay thread spawn.
-  ``submit_batch`` enqueues a job set atomically with respect to
-  ``shutdown()``; post-shutdown submits raise deterministically.
+  draining a FIFO job queue, so repeated runs/steps never pay thread spawn,
+  and behind it the group's completion thread: together the group's
+  in-order queue, on which a worker dispatches run N+1 while run N is
+  still on the device.  ``submit_batch`` enqueues a job set atomically
+  with respect to ``shutdown()``; post-shutdown submits raise
+  deterministically.
 - ``RunHandle``    — future-like per-run state: completion event, a private
   ``Introspector``, a lock-protected error list, and the run's *graph*
   edges: predecessor handles, run-scoped buffer write versions, and an
@@ -19,10 +22,11 @@ kernel launches.  This module is that runtime for the JAX port:
   write-after-write, write-after-read on buffer identity).  Dependent runs
   wait on their predecessors **on the worker threads**, never on the host:
   a group's persistent worker starts its portion of run N+1 the moment run
-  N is safe for it, so chains of linked Programs pipeline without a host
-  barrier per stage.  A failed predecessor *poisons* dependents — they
-  complete immediately with a ``RunError`` instead of running on stale
-  inputs (or hanging).
+  N is safe for it — for a run pinned to the group that chains on runs
+  queued there, the moment run N is *dispatched* — so chains of linked
+  Programs pipeline without a host barrier per stage.  A failed predecessor
+  *poisons* dependents — they complete immediately with a ``RunError``
+  instead of running on stale inputs (or hanging).
 
 ``EngineCL`` is a facade over this: ``run()`` = ``submit()`` + wait, with
 identical blocking semantics; ``run_pipeline``/``run_iterative`` submit
@@ -35,8 +39,6 @@ import threading
 import time
 import traceback
 from typing import Callable, List, Optional, Sequence
-
-import jax
 
 from repro.core.device import DeviceGroup
 from repro.core.introspector import Introspector, PackageRecord
@@ -105,6 +107,12 @@ class RunHandle:
         self._callbacks: List[Callable[["RunHandle"], None]] = []
         self._prepared = False
         self._prepare_done = threading.Event()
+        # The buffers this run writes, as its Program listed them when the
+        # run was prepared: its host copies land there even when an
+        # epilogue has since swapped the Program's lists (see ``outputs``).
+        self.outputs: list = []
+        # Dispatched before a predecessor had completed (in-order queue).
+        self.ahead = False
         # One fresh version per (run, buffer) — see version_for_write.
         self._write_versions: dict[int, Optional[int]] = {}
         # Submit-time snapshot of the buffer sets, used by later submits to
@@ -117,19 +125,24 @@ class RunHandle:
     @property
     def on_one_group(self) -> bool:
         """Pinned to exactly one device group: the run keeps its Program's
-        ``Resident`` buffers on that group's device (no host write-back); a
-        run split across several groups brings them to host first."""
+        ``Resident`` buffers on that group's device (no host write-back) and
+        goes through the group's in-order queue; a run split across several
+        groups brings them to host first."""
         return len(self.targets) == 1
 
+    def queued_on(self, group: DeviceGroup) -> bool:
+        """Pinned to ``group`` alone: completes on its in-order queue."""
+        return self.on_one_group and self.targets[0] is group
+
     # -- worker-facing -----------------------------------------------------
-    def _mark_started(self) -> None:
-        """First worker to pick up the run stamps t_run_start — metrics of
-        queued async runs must not include the wait behind earlier runs."""
+    def _mark_started(self, t: Optional[float] = None) -> None:
+        """Stamp t_run_start (now, or ``t``) once — metrics of queued async
+        runs must not include the wait behind earlier runs."""
         with self._lock:
             if self._started:
                 return
             self._started = True
-        self.introspector.start_run()
+        self.introspector.start_run(t)
 
     def _ensure_prepared(self, groups) -> None:
         """Per-run ``prepare`` ordering: the scheduler clone is prepared by
@@ -144,6 +157,7 @@ class RunHandle:
             self._prepared = True
         if first:
             try:
+                self.outputs = list(self.program._outs)
                 if not self.on_one_group:
                     self.program.to_host()
                 self.scheduler.prepare(
@@ -189,16 +203,23 @@ class RunHandle:
             "poisoned: upstream run failed (" + "; ".join(ups) + ")"
         )
 
+    def _run_epilogue(self) -> None:
+        """Run the epilogue once, unless the run has failed."""
+        with self._lock:
+            fn, self._epilogue = self._epilogue, None
+        if fn is None or self.has_errors():
+            return
+        try:
+            fn()
+        except BaseException:  # noqa: BLE001 — must surface, not hang
+            self.record_error(f"epilogue: {traceback.format_exc()}")
+
     def _worker_finished(self) -> None:
         with self._lock:
             self._pending_workers -= 1
             last = self._pending_workers <= 0
         if last:
-            if self._epilogue is not None and not self.has_errors():
-                try:
-                    self._epilogue()
-                except BaseException:  # noqa: BLE001 — must surface, not hang
-                    self.record_error(f"epilogue: {traceback.format_exc()}")
+            self._run_epilogue()
             if self._started:
                 self.introspector.end_run()
             self._finalize()
@@ -273,6 +294,19 @@ class RunHandle:
         return self.introspector.summary()
 
 
+def _block(res) -> None:
+    """Wait until a package's results are computed.  A result a later run on
+    the same device has since taken and donated (a ``Resident`` value kept
+    here) is deleted: that run is queued behind this one, so the others
+    tell when this one ended."""
+    for x in (res if isinstance(res, (tuple, list)) else (res,)):
+        try:
+            x.block_until_ready()
+        except Exception:  # noqa: BLE001 — donated, or a device error
+            if not x.is_deleted():
+                raise
+
+
 def conflicts(reads: frozenset, writes: frozenset, other: RunHandle) -> bool:
     """True when a run reading ``reads``/writing ``writes`` (host-buffer ids)
     must be ordered after ``other``: read-after-write, write-after-write, or
@@ -281,32 +315,47 @@ def conflicts(reads: frozenset, writes: frozenset, other: RunHandle) -> bool:
 
 
 class GroupExecutor:
-    """One persistent worker thread per DeviceGroup, FIFO job order.
+    """One persistent worker thread per DeviceGroup, FIFO job order, and
+    behind each worker its group's completion thread.
 
-    Jobs for one group run serially on its thread (a device computes
-    packages serially); jobs across groups run concurrently.  Also reused by
-    HeteroTrainer so training steps don't re-spawn threads either."""
+    Jobs for one group start serially on its worker (a device computes
+    packages serially); jobs across groups run concurrently.  A job may
+    hand the rest of its run to :meth:`complete`: the group's completion
+    thread runs what it is handed in that order, so the worker can go on to
+    dispatch the next run while this one is still on the device — the
+    group's in-order queue.  Also reused by HeteroTrainer so training steps
+    don't re-spawn threads either."""
 
     def __init__(self, groups: Sequence[DeviceGroup], name: str = "enginecl") -> None:
-        self.groups = list(groups)
+        self.groups: List[DeviceGroup] = []
         self._queues: dict[int, "queue.Queue"] = {}
+        self._done_queues: dict[int, "queue.Queue"] = {}
         self._threads: List[threading.Thread] = []
         self._lock = threading.Lock()  # guards _alive vs. enqueue atomically
         self._alive = True
-        for i, g in enumerate(self.groups):
-            q: "queue.Queue" = queue.Queue()
-            self._queues[id(g)] = q
-            t = threading.Thread(
-                target=self._worker, args=(q,), name=f"{name}-{g.name}-{i}", daemon=True
-            )
+        for g in groups:
+            self._start(g, name)
+
+    def _start(self, group: DeviceGroup, name: str) -> None:
+        q: "queue.Queue" = queue.Queue()
+        dq: "queue.Queue" = queue.Queue()
+        self._queues[id(group)] = q
+        self._done_queues[id(group)] = dq
+        i = len(self.groups)
+        self.groups.append(group)
+        for target, args, suffix in ((self._worker, (q, dq), ""),
+                                     (self._completer, (dq,), "-done")):
+            t = threading.Thread(target=target, args=args, daemon=True,
+                                 name=f"{name}-{group.name}-{i}{suffix}")
             t.start()
             self._threads.append(t)
 
     @staticmethod
-    def _worker(q: "queue.Queue") -> None:
+    def _worker(q: "queue.Queue", dq: "queue.Queue") -> None:
         while True:
             job = q.get()
             if job is None:
+                dq.put(None)  # after every completion this worker handed on
                 return
             fn, on_done = job
             try:
@@ -317,6 +366,17 @@ class GroupExecutor:
                 if on_done is not None:
                     on_done()
 
+    @staticmethod
+    def _completer(dq: "queue.Queue") -> None:
+        while True:
+            fn = dq.get()
+            if fn is None:
+                return
+            try:
+                fn()
+            except BaseException:  # noqa: BLE001 — as the worker
+                pass
+
     @property
     def alive(self) -> bool:
         with self._lock:
@@ -324,21 +384,12 @@ class GroupExecutor:
 
     def add_group(self, group: DeviceGroup, name: str = "enginecl") -> None:
         """Attach a new group at runtime (elastic join): fresh queue + worker
-        thread, atomic with respect to shutdown.  Idempotent per group."""
+        threads, atomic with respect to shutdown.  Idempotent per group."""
         with self._lock:
             if not self._alive:
                 raise RuntimeError("executor is shut down")
-            if id(group) in self._queues:
-                return
-            q: "queue.Queue" = queue.Queue()
-            self._queues[id(group)] = q
-            self.groups.append(group)
-            t = threading.Thread(
-                target=self._worker, args=(q,),
-                name=f"{name}-{group.name}-{len(self._threads)}", daemon=True,
-            )
-            t.start()
-            self._threads.append(t)
+            if id(group) not in self._queues:
+                self._start(group, name)
 
     def submit(self, group: DeviceGroup, fn: Callable[[], None],
                on_done: Optional[Callable[[], None]] = None) -> None:
@@ -354,6 +405,18 @@ class GroupExecutor:
                 raise RuntimeError("executor is shut down")
             for group, fn, on_done in jobs:
                 self._queues[id(group)].put((fn, on_done))
+
+    def complete(self, group: DeviceGroup, fn: Callable[[], None]) -> None:
+        """Hand ``fn`` to ``group``'s completion thread (from its worker):
+        it runs after everything handed over before it."""
+        self._done_queues[id(group)].put(fn)
+
+    def drain(self, group: DeviceGroup) -> None:
+        """Block (on ``group``'s worker) until its completion thread has run
+        everything handed over so far."""
+        done = threading.Event()
+        self.complete(group, done.set)
+        done.wait()
 
     def shutdown(self) -> None:
         with self._lock:
@@ -381,6 +444,9 @@ class Runtime:
         self.executor = GroupExecutor(self.groups)
         self._submit_lock = threading.Lock()
         self._inflight: List[RunHandle] = []
+        # id(group) -> when its last package completed (perf_counter): the
+        # earliest a package queued behind it can have started on the device.
+        self._t_free: dict[int, float] = {}
 
     @property
     def alive(self) -> bool:
@@ -417,10 +483,14 @@ class Runtime:
         disjoint groups over disjoint buffers proceed concurrently while
         shared-buffer runs stay ordered.
 
-        ``epilogue`` (if given) runs exactly once on the last worker after a
-        successful run, before the handle completes — dependents observe its
-        effects (e.g. ``swap_buffers``).  Validation errors complete the
-        handle immediately (``result()`` raises ``RunError``)."""
+        ``epilogue`` (if given) runs exactly once after a successful
+        dispatch, before any later run on the group is dispatched — for a
+        run pinned to one group, as soon as its packages are dispatched and
+        handed off on the device; for a run split across groups, on the
+        last worker to finish it, before the handle completes.  Dependents
+        observe its effects (e.g. ``swap_buffers``).  Validation errors
+        complete the handle immediately (``result()`` raises
+        ``RunError``)."""
         targets = list(groups) if groups else self.groups
         deps: List[RunHandle] = []
         if after is not None:
@@ -453,7 +523,7 @@ class Runtime:
                 handle._fail(errs)
                 return handle
             self.executor.submit_batch([
-                (g, (lambda g=g, h=handle: self._process(g, h)), handle._worker_finished)
+                (g, (lambda g=g, h=handle: self._process(g, h)), None)
                 for g in targets
             ])
             self._inflight.append(handle)
@@ -463,49 +533,97 @@ class Runtime:
         self.executor.shutdown()
 
     # --------------------------------------------------------------- workers
-    def _await_deps(self, handle: RunHandle) -> bool:
-        """Block this worker until every predecessor run completed; returns
-        False (poisoning the handle) when any predecessor failed.  Safe from
-        deadlock: dependencies always precede their dependents in every
-        group's FIFO queue (submit order), and cross-group progress is
-        independent."""
-        ok = True
-        for dep in handle.deps:
+    @staticmethod
+    def _await_deps(deps: Sequence[RunHandle]) -> None:
+        """Block this worker until every run in ``deps`` completed.  Safe
+        from deadlock: dependencies always precede their dependents in every
+        group's FIFO queue (submit order), completion threads only wait on
+        the device, and cross-group progress is independent."""
+        for dep in deps:
             dep._done.wait()
-            if dep.has_errors():
-                ok = False
-        if not ok:
-            handle._poison()
-        return ok
 
     def _process(self, group: DeviceGroup, handle: RunHandle) -> None:
         """Paper's Device thread body: pull → enqueue (async) → complete →
         write, against this run's scheduler/introspector/error list.
 
+        A run pinned to this one group goes through the group's in-order
+        queue.  Its worker waits only for the dependencies that are not
+        queued on this group; one that is has been dispatched already, and
+        its outputs are on the device (``_hand_off``).  The worker then
+        dispatches the run, hands its outputs off on the device, runs its
+        epilogue, and leaves the waits for the device, the host copies and
+        the completion to the group's completion thread (``_complete``), in
+        dispatch order.  So a chained run is dispatched while its
+        predecessor still runs (``RunHandle.ahead``).  A run split across
+        groups waits until this group's queue is empty and its
+        dependencies completed, and completes on the workers.
+
         On the group's track a run reads ``queue_wait`` (submit → this
         worker picks it up) → ``dep_wait`` (predecessors) → per package
         ``dispatch`` (with ``upload`` inside) → ``execute`` →
         ``write_back``."""
+        tr = tracer()
+        queued = handle.queued_on(group)
+        pending: list = []  # (offset, size, result, t_enqueue, kept bytes)
+        try:
+            if tr.enabled:
+                tr.complete("queue_wait", handle.t_submit, time.perf_counter(),
+                            track=f"group/{group.name}",
+                            kernel=handle.program.label)
+            if not queued:
+                self.executor.drain(group)
+            waits = [d for d in handle.deps if not (queued and d.queued_on(group))]
+            if waits:
+                with tr.span("dep_wait", track=f"group/{group.name}",
+                             kernel=handle.program.label, deps=len(waits)):
+                    self._await_deps(waits)
+            if any(d.has_errors() for d in handle.deps):
+                handle._poison()
+            else:
+                self._dispatch(group, handle, pending)
+        except BaseException:  # noqa: BLE001 — surfaced via RunHandle errors
+            handle.record_error(f"{group.name}: {traceback.format_exc()}")
+        finally:
+            if queued:
+                self.executor.complete(
+                    group, lambda: self._complete(group, handle, pending))
+            else:
+                handle._worker_finished()
+
+    def _dispatch(self, group: DeviceGroup, handle: RunHandle,
+                  pending: list) -> None:
+        """Dispatch this group's packages of the run and hand each off on the
+        device.  A queued run (pinned to this group) dispatches them all and
+        runs its epilogue; its packages complete in ``_complete``.  A split
+        run blocks on its oldest package once ``pipeline_depth`` are in
+        flight and completes them here.
+
+        A queued run reads what an unfinished dependency produced on the
+        device (a ``Resident`` kept here, or a slice stashed in this group's
+        transfer cache); where it would read such a buffer from host
+        instead, or donate a device copy the dependency has yet to copy to
+        host, it first waits for the dependencies to complete."""
         prog, sched = handle.program, handle.scheduler
         tr = tracer()
         track = f"group/{group.name}"
-        if tr.enabled:
-            tr.complete("queue_wait", handle.t_submit, time.perf_counter(),
-                        track=track, kernel=prog.label)
-        ok = True
-        if handle.deps:
-            with tr.span("dep_wait", track=track, kernel=prog.label,
-                         deps=len(handle.deps)):
-                ok = self._await_deps(handle)
-        if not ok:
-            return
-        handle._mark_started()
-        # Per-run transfer accounting: runs on one group serialize on its
-        # worker thread, so the cumulative-counter delta around this run is
-        # exactly what this run caused on this group.
+        queued = handle.queued_on(group)
+        unfinished = [d for d in handle.deps if not d.done()]
+        unwritten = {id(b) for d in unfinished for b in d.outputs}
+
+        def host_read(buf) -> None:
+            if id(buf) in unwritten:
+                with tr.span("dep_wait", track=track, kernel=prog.label,
+                             deps=len(unfinished)):
+                    self._await_deps(unfinished)
+                unwritten.clear()
+                unfinished.clear()
+
+        if not queued:
+            handle._mark_started()
+        # Per-run transfer accounting: runs on one group dispatch serially on
+        # its worker thread, so the cumulative-counter delta around this
+        # dispatch is exactly what this run caused on this group.
         xfer0, hits0 = group.n_transfers, group.n_cache_hits
-        keep = handle.on_one_group
-        pending: list = []  # (offset, size, result, t_enqueue)
         try:
             handle._ensure_prepared(handle.targets or self.groups)
             while True:
@@ -514,39 +632,28 @@ class Runtime:
                     off, size = pkg
                     t_enq = time.perf_counter()
                     res = group.execute_chunk(prog, off, size,  # async
-                                              keep_resident=keep)
+                                              keep_resident=handle.on_one_group,
+                                              host_read=host_read)
                     if tr.enabled:
                         # Host-side dispatch cost only: the device compute is
                         # still in flight — it becomes the "execute" span.
                         tr.complete("dispatch", t_enq, time.perf_counter(),
                                     track=track, kernel=prog.label,
                                     offset=off, size=size)
-                    pending.append((off, size, res, t_enq))
-                if pkg is None and not pending:
+                    kept = self._hand_off(group, handle, off, size, res)
+                    pending.append((off, size, res, t_enq, kept))
+                elif queued or not pending:
                     break
-                # Block on the oldest package once the pipeline is full (or
-                # the stream ended) — transfers/compute of newer packages
-                # overlap with this wait.
-                if pending and (len(pending) >= self.pipeline_depth or pkg is None):
-                    off, size, res, t_enq = pending.pop(0)
-                    jax.block_until_ready(res)  # async: service time to completion
-                    t_dev = time.perf_counter()
-                    cost = prog.cost_fn(off, size) if prog.cost_fn else None
-                    group.simulate_service_time(size, t_dev - t_enq, cost)
-                    t_end = time.perf_counter()
-                    # Device service time (plus simulated padding), measured
-                    # ONCE — host write-back below must not inflate what
-                    # adaptive raters (HGuided/ThroughputRater) observe.
-                    service = t_end - t_enq
-                    with tr.span("write_back", track=track, offset=off,
-                                 size=size) as sp:
-                        nbytes, kept = self._write_back(group, handle, off,
-                                                        size, res)
-                        sp.set(bytes=nbytes, kept_bytes=kept)
-                    handle.introspector.record(
-                        PackageRecord(group.name, off, size, t_enq, t_enq, t_end)
-                    )
-                    sched.observe(group, size, service)
+                # A split run blocks on the oldest package once the pipeline
+                # is full (or the stream ended) — transfers/compute of newer
+                # packages overlap with this wait.
+                if not queued and (len(pending) >= self.pipeline_depth
+                                   or pkg is None):
+                    self._finish_package(group, handle, *pending.pop(0))
+            # Dispatched while a dependency was still unfinished.
+            handle.ahead = queued and bool(unfinished)
+            if queued:
+                handle._run_epilogue()
         except BaseException:  # noqa: BLE001 — surfaced via RunHandle error
             # API.  BaseException, not Exception: a KeyboardInterrupt/
             # SystemExit escaping from kernel code must still be recorded
@@ -558,31 +665,79 @@ class Runtime:
                 group.name, group.n_transfers - xfer0,
                 group.n_cache_hits - hits0)
 
-    def _write_back(self, group: DeviceGroup, handle: RunHandle,
-                    off: int, size: int, res) -> tuple:
-        """Host write-back + device-resident handoff: the produced device
-        slices are stashed in this group's transfer cache under the run's
-        write version, so a dependent run reading the same elements on the
-        same group skips the host re-read and the ``jax.device_put``.
+    def _complete(self, group: DeviceGroup, handle: RunHandle,
+                  pending: list) -> None:
+        """Completion thread of ``group``: finish a queued run after every run
+        dispatched on the group before it.  Its service starts at the later
+        of its dispatch and the group's previous completion.  A run whose
+        dependency failed meanwhile is poisoned, and nothing of it is
+        written back."""
+        try:
+            if any(d.has_errors() for d in handle.deps):
+                handle._poison()
+            elif pending and not handle.has_errors():
+                handle._mark_started(max(pending[0][3],
+                                         self._t_free.get(id(group), 0.0)))
+                for pkg in pending:
+                    self._finish_package(group, handle, *pkg)
+        except BaseException:  # noqa: BLE001 — surfaced via RunHandle errors
+            handle.record_error(f"{group.name}: {traceback.format_exc()}")
+        finally:
+            handle._worker_finished()
+
+    def _finish_package(self, group: DeviceGroup, handle: RunHandle,
+                        off: int, size: int, res, t_enq: float,
+                        kept: int) -> None:
+        """Wait for one dispatched package, then copy its host outputs back
+        (the ``write_back`` span).  Its device service time, measured ONCE
+        from the later of its dispatch and the group's previous completion
+        (plus simulated padding), is what adaptive raters
+        (HGuided/ThroughputRater) observe: host write-back must not
+        inflate it."""
+        prog = handle.program
+        _block(res)
+        t0 = max(t_enq, self._t_free.get(id(group), 0.0))
+        cost = prog.cost_fn(off, size) if prog.cost_fn else None
+        group.simulate_service_time(size, time.perf_counter() - t0, cost)
+        t_end = time.perf_counter()
+        self._t_free[id(group)] = t_end
+        with tracer().span("write_back", track=f"group/{group.name}",
+                           offset=off, size=size) as sp:
+            nbytes = prog.write_outputs(off, size, res, bump=False,
+                                        keep_resident=handle.on_one_group,
+                                        outs=handle.outputs)
+            group.count_d2h(nbytes)
+            sp.set(bytes=nbytes, kept_bytes=kept)
+        handle.introspector.record(
+            PackageRecord(group.name, off, size, t0, t0, t_end))
+        handle.scheduler.observe(group, size, t_end - t0)
+
+    def _hand_off(self, group: DeviceGroup, handle: RunHandle,
+                  off: int, size: int, res) -> int:
+        """Device-resident handoff of one package at dispatch (its results
+        are futures): the produced device slices are stashed in this group's
+        transfer cache under the run's write version, so a dependent run
+        reading the same elements on the same group skips the host re-read
+        and the ``jax.device_put`` — and need not wait for this run to
+        complete.
 
         A run pinned to one group copies nothing to host for its
         ``Resident`` outputs: their device results become the buffers'
         values (``Resident.keep``), outside the evictable cache.
 
-        Returns (and counts on ``group``) the bytes copied to host and the
-        ``Resident`` bytes kept on the device."""
+        Returns (and counts on ``group``) the ``Resident`` bytes kept on the
+        device."""
         prog = handle.program
         results = res if isinstance(res, (tuple, list)) else (res,)
-        keep = handle.on_one_group
-        nbytes = prog.write_outputs(off, size, results, bump=False,
-                                    keep_resident=keep)
+        if len(results) != len(handle.outputs):
+            raise ValueError(f"kernel returned {len(results)} outputs, "
+                             f"program has {len(handle.outputs)}")
         kept = 0
-        for b, r in zip(prog._outs, results):
+        for b, r in zip(handle.outputs, results):
             version = handle.version_for_write(b)
-            if keep and isinstance(b, Resident):
+            if handle.on_one_group and isinstance(b, Resident):
                 kept += b.keep(group, *prog.rows_of(b, off, size), r)
             else:
                 group.stash_output(prog, b, off, size, r, version)
-        group.count_d2h(nbytes)
         group.count_kept(kept)
-        return nbytes, kept
+        return kept

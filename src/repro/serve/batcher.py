@@ -17,7 +17,8 @@ batch and the runtime —
 - ``BatchGroup``     — one live continuous batch: ``n_slots`` KV-cache
   slots backed by slot-leading buffers that form a single ``Program``,
   decoding in fixed-length segments submitted through
-  ``Runtime.submit(after=prev_segment)``.
+  ``Runtime.submit(after=prev_segment)`` — on one device group, one segment
+  ahead of the one running (``BatchGroup.advance``).
 
 Where the cache lives: when every run of the batch executes on one device
 group (``BatchGroup.home``: a sole group, or a ``group_batches`` member),
@@ -42,11 +43,14 @@ its decode spans (asserted in tests/test_server.py via
 ``DeviceGroup.n_transfers``).
 
 Requests *exit* at segment boundaries (their slot is left to decode
-garbage — shapes are static — until a joiner overwrites the full slot row,
+garbage — shapes are static; a segment queued ahead may take it one segment
+past the cache's last row, where writes are dropped — until a joiner
+overwrites the full slot row,
 which is what makes slot reuse safe: a join rewrites token, position, and
 every cache leaf row, so no stale KV survives).  Requests *join* at
 segment boundaries after their prefill — submitted as its own Program,
-concurrently with the in-flight segment — completes.
+concurrently with the in-flight segment — completes; such a boundary drains
+first (no segment ahead).
 
 With multiple DeviceGroups the segment Program's slot axis is split by the
 engine's scheduler (Static/Dynamic/HGuided) exactly like any co-executed
@@ -841,12 +845,22 @@ class BatchGroup:
         # device.  None: runs split across groups, or a kernel-only group
         # (no runtime), keep host mirrors of the cache.
         self.home = self._home_group()
+        # A plain segment chained on one group can be queued on the device
+        # behind the one in flight (``Runtime._process``): this batch then
+        # keeps one segment ahead (:meth:`advance`).
+        self.ahead = self.home is not None and not (self.spec_k or chunk_len)
+        # Harvest-only outputs (never inputs) rotate with a spare at each
+        # segment, so a harvest reads the buffers its own segment wrote.
+        self._spares: dict = {}
         self._row_bytes = kernels.row_bytes() if kernels.counted else 0
         with tracer().span("form_group", track="batcher", bucket=bucket):
             self._build_segment_program()
-        self.seg_handle = None
+        # Segments in flight, oldest first: (handle, request per slot when
+        # it was submitted, submit time).
+        self._segs: List[tuple] = []
         self.prev_handle = None
-        self._seg_t0 = 0.0
+        self._draining = False  # no segment ahead until the next boundary
+        self._drain_t0: Optional[float] = None
         # -- in-flight prefill wave ----------------------------------------
         self.prefill_handle = None
         self.prefill_wave: List[object] = []
@@ -927,6 +941,10 @@ class BatchGroup:
             prog.out(_blank(b))
         if kernels.counted:  # the per-slot counters, after the leaves
             prog.out(np.zeros((n_slots, len(COUNTERS)), np.int32))
+        if self.ahead:
+            self._spares = {k: np.zeros_like(prog._outs[k]) for k in
+                            ([0, len(prog._outs) - 1] if kernels.counted
+                             else [0])}
         prog.kernel(kernels.segment_kernel(seg_len), f"decode_seg{seg_len}")
         # Donate the cache-leaf inputs (mirroring make_generate's
         # donate_argnums=(1,)): each segment's jitted kernel updates the KV
@@ -1004,6 +1022,11 @@ class BatchGroup:
         self._ctok_out = 4
 
     # ------------------------------------------------------------- queries
+    @property
+    def seg_handle(self):
+        """The oldest segment in flight, or None."""
+        return self._segs[0][0] if self._segs else None
+
     def free_slots(self) -> List[int]:
         return [i for i, r in enumerate(self.slots) if r is None]
 
@@ -1211,10 +1234,10 @@ class BatchGroup:
 
     # ------------------------------------------------------------ segments
     def submit_segment(self, notify: Callable) -> None:
-        """Chain the next decode segment after the previous one.  The swap
-        epilogue runs worker-side, so the just-produced token/pos/cache
-        buffers become the next segment's inputs *device-resident*."""
-        assert self.seg_handle is None
+        """Chain the next decode segment after the newest one (in flight, or
+        else the last harvested).  The swap epilogue runs worker-side, so
+        the just-produced token/pos/cache buffers become the next segment's
+        inputs *device-resident*."""
         if self.spec_k and self.spec_gate is not None:
             # SpecGate auto-bypass: decide this segment's mode and flip the
             # device-side flag only when it changes (one tiny re-upload).
@@ -1224,17 +1247,61 @@ class BatchGroup:
                 self.prog.invalidate(self._spec_on)
             self._seg_mode = "spec" if want else "plain"
 
-        def epilogue(prog=self.prog, pairs=self._swap_pairs):
+        def epilogue(prog=self.prog, pairs=self._swap_pairs,
+                     spares=self._spares):
             for i_in, i_out in pairs:
                 prog.swap_buffers(i_in, i_out)
+            for k, buf in spares.items():
+                spares[k], prog._outs[k] = prog._outs[k], buf
 
-        after = [self.prev_handle] if self.prev_handle is not None else None
-        self._seg_t0 = _now()
+        prev = self._segs[-1][0] if self._segs else self.prev_handle
+        t0 = _now()
         h = self.runtime.submit(self.prog, self.scheduler,
-                                after=after, epilogue=epilogue,
-                                groups=self.target)
-        self.seg_handle = h
+                                after=[prev] if prev is not None else None,
+                                epilogue=epilogue, groups=self.target)
+        self._segs.append((h, list(self.slots), t0))
         h.add_done_callback(lambda _h: notify())
+
+    def advance(self, notify: Callable, drain: bool = False) -> None:
+        """Keep this batch's decode segments in flight: one whenever a slot
+        holds a request and, when segments may run ahead (:attr:`ahead`), a
+        second chained behind it, which the device starts as soon as the
+        first ends — so the host's work between segments leaves the
+        device's critical path.  No segment goes ahead when the next
+        boundary has to drain: a join is coming (a prefill wave in flight
+        or waiting to merge), or ``drain`` (the caller will move slots
+        there), which holds until that boundary; nor when no request still
+        needs tokens after the one in flight.  At a boundary with a join
+        coming, the next segment waits for the merge: the prefill is on
+        the device ahead of it anyway, and it then decodes the joiners
+        too.
+
+        A drain is traced as a ``drain`` span on the batcher track, from
+        the moment the boundary had to drain to its resubmission: the
+        device idles at its end."""
+        if not self._segs:  # at a boundary
+            if self._drain_t0 is not None:
+                tr = tracer()
+                if tr.enabled:
+                    tr.complete("drain", self._drain_t0, time.perf_counter(),
+                                track="batcher", bucket=self.bucket)
+                self._drain_t0 = None
+            self._draining = drain
+        elif drain:
+            self._draining = True
+        if not any(self.slots):
+            return
+        if not self._segs and not (self.ahead and self.prefill_handle is not None):
+            self.submit_segment(notify)
+        if not self.ahead:
+            return
+        if self._draining or self.prefill_handle is not None:
+            if self._drain_t0 is None:
+                self._drain_t0 = time.perf_counter()
+        elif len(self._segs) == 1 and any(
+                r is not None and r.remaining() > self.seg_len
+                for r in self.slots):
+            self.submit_segment(notify)
 
     def harvest_segment(self) -> dict:
         """Collect a completed segment: append each active slot's new tokens
@@ -1242,24 +1309,28 @@ class BatchGroup:
         requests, and free their slots.  Returns stats for this segment.
         The server calls it under its lock, in a ``harvest`` span:
         ``submit`` waits behind it."""
-        h = self.seg_handle
-        assert h is not None and h.done()
-        self.seg_handle = None
-        seconds = h.metrics.get("response_time") or (_now() - self._seg_t0)
+        h, snapshot, t0 = self._segs.pop(0)
+        assert h.done()
+        seconds = h.metrics.get("response_time") or (_now() - t0)
         if h.has_errors():
             return {"errors": h.errors(), "seconds": seconds}
         self.prev_handle = h
         self.last_run_metrics = h.metrics
-        # toks_seg is out 0 and never ping-ponged: stable across segments.
-        toks_seg = self.prog._outs[0]
-        cnt = self.prog._outs[1] if self.spec_k else None
+        # The host buffers this segment wrote: a later segment writes others.
+        outs = h.outputs
+        toks_seg = outs[0]
+        cnt = outs[1] if self.spec_k else None
         n_active = 0
         finished = []
         emitted = drafted = accepted = chunk_tokens = delivered = 0
         rows_read = 0  # cache rows the active slots' steps attended
         tr = tracer()
         traced = tr.enabled
-        for slot, req in self.active():
+        # The requests the segment decoded for that still hold their slot: one
+        # that finished in the segment before decoded a segment too many.
+        for slot, req in enumerate(snapshot):
+            if req is None or self.slots[slot] is not req:
+                continue
             if self.chunk_len and req.chunk_pos < self.bucket:
                 # Prefilling at segment entry: the chunk stage advanced the
                 # cursor deterministically — mirror it host-side.  On the
@@ -1275,7 +1346,7 @@ class BatchGroup:
                                      cursor=req.chunk_pos,
                                      tokens=req.chunk_pos - old)
                 if req.chunk_pos >= self.bucket:
-                    ctok = self.prog._outs[self._ctok_out]
+                    ctok = outs[self._ctok_out]
                     req.board(slot, int(ctok[slot, 0]))
                     delivered += 1
                     if traced:
@@ -1329,11 +1400,12 @@ class BatchGroup:
         if self.kernels.counted and not (self.spec_k or self.chunk_len):
             # Over every slot, as the step computed them; the latent bytes
             # at the active slots' real lengths.
-            n = self.prog._outs[-1].sum(axis=0)
+            n = outs[-1].sum(axis=0)
             counts = {k: int(v) for k, v in zip(COUNTERS, n)}
             counts["latent_bytes"] = rows_read * self._row_bytes
         _trace_run(tr, "segment", h, bucket=self.bucket, n_active=n_active,
-                   finished=len(finished), chunk_tokens=chunk_tokens, **counts)
+                   finished=len(finished), chunk_tokens=chunk_tokens,
+                   ahead=int(h.ahead), **counts)
         if self.telemetry is not None and chunk_tokens:
             self.telemetry.count("chunk_tokens", chunk_tokens)
         res = {"n_active": n_active, "finished": finished, "seconds": seconds,
@@ -1438,7 +1510,8 @@ class BatchGroup:
         victims = [r for _, r in self.active()] + list(self.prefill_wave)
         self.slots = [None] * self.n_slots
         self.prefill_wave = []
-        self.seg_handle = None
+        self._segs = []
+        self._drain_t0 = None
         self.prefill_handle = None
         return victims
 

@@ -884,8 +884,7 @@ class InferenceServer:
         # running segment so joiners are ready at the next boundary.
         if grp.prefill_handle is None:
             self._board(grp, now)
-        if grp.seg_handle is None and any(grp.slots):
-            grp.submit_segment(self._notify)
+        grp.advance(self._notify)
 
     def _harvest_merge(self, grp: BatchGroup, gname: Optional[str]) -> bool:
         """Harvest a finished segment and merge a finished prefill (cv
@@ -898,7 +897,7 @@ class InferenceServer:
         and the invalidation of the whole cache).  Both run under the
         server's lock: they are what ``submit`` waits behind."""
         tr = tracer()
-        if grp.seg_handle is not None and grp.seg_handle.done():
+        while grp.seg_handle is not None and grp.seg_handle.done():
             with tr.span("harvest", track="batcher", bucket=grp.bucket):
                 res = grp.harvest_segment()
             if "errors" in res:
@@ -1050,17 +1049,24 @@ class InferenceServer:
                          now: float) -> None:
         """One scheduling pass over a bucket's member groups: harvest and
         merge each, apply drain and policy migrations at the boundaries
-        that line up, place the join wave, chain next segments."""
+        that line up, place the join wave, chain next segments.
+
+        A member the policy moved slots of runs no segment ahead until its
+        next boundary, so that it is at one again after a segment (a policy
+        that waits for a common boundary reaches it); while a draining
+        member still holds slots, no member runs one ahead."""
         self._ensure_members(bucket, members)
         for nm in list(members):
             self._harvest_merge(members[nm], nm)
         live = {nm: m for nm, m in members.items() if not m.dead}
         hold: set = set()
+        moved: set = set()
         if len(live) > 1:
             self._drain_migrations(live)
             weights = self._member_weights(bucket, live)
             moves, hold = self._policy.plan(live, weights)
             for src, slot, dst in moves:
+                moved.update((src, dst))
                 ok = live[src].migrate_slot_to(slot, live[dst])
                 if ok:
                     self._stats["slot_migrations"] += 1
@@ -1072,18 +1078,20 @@ class InferenceServer:
                     weights={k: round(w, 4) for k, w in weights.items()},
                     **getattr(self._policy, "last_info", {}))
         self._board_members(bucket, live, now, hold)
+        leaving = any(nm in self._draining and any(m.slots)
+                      for nm, m in live.items())
         for nm, grp in live.items():
-            if grp.seg_handle is not None or nm in hold:
+            if nm in hold:
                 continue
-            if nm in self._draining and any(grp.slots):
+            if (nm in self._draining and any(grp.slots)
+                    and grp.seg_handle is None):
                 others = [m for o, m in live.items()
                           if o != nm and o not in self._draining]
                 if others and any(not m.at_boundary() for m in others):
                     # An acceptor's boundary is coming: hold this member's
                     # slots at the boundary so they can migrate out then.
                     continue
-            if any(grp.slots):
-                grp.submit_segment(self._notify)
+            grp.advance(self._notify, drain=leaving or nm in moved)
 
     def _drain_migrations(self, members: dict) -> None:
         """Move every slot of draining members that can leave right now to

@@ -1,4 +1,5 @@
 """Persistent runtime: async submit(), run-scoped errors, transfer cache,
+the in-order group queue (dispatch ahead, completion in order),
 device discovery normalization."""
 import threading
 
@@ -16,6 +17,8 @@ from repro.core import (
     Static,
     discover,
 )
+from repro.core.runtime import Runtime
+from repro.core.trace import Tracer, set_tracer
 
 
 def saxpy(offset, x):
@@ -280,3 +283,221 @@ def test_done_callback_exception_does_not_break_worker_or_later_callbacks():
     eng.program(p2).run()
     assert not eng.has_errors(), eng.get_errors()
     np.testing.assert_allclose(y2, 2.0 * x2 + 1.0)
+
+
+# ------------------------------------------------------ in-order group queue
+
+QN, QT = 64, 0.2  # work-items; simulated device seconds per run
+
+
+def double_plus(offset, x):
+    return x * 2.0 + 1.0
+
+
+def qprog(cost_fn=None):
+    """``state`` ping-pongs between runs (in 0 / out 0), handed off on the
+    device; the kernel never donates it."""
+    p = Program().in_(np.arange(QN, dtype=np.float32)).out(
+        np.zeros(QN, np.float32)).kernel(double_plus, "qchain").work_items(QN, 1)
+    p.cost_fn = cost_fn
+    return p
+
+
+def qchain(prog, group, runs, *, serial=False, cbs=None):
+    """``runs`` chained runs of ``prog`` pinned to ``group``: all submitted
+    at once, or (``serial``) each after the last completed."""
+    rt = Runtime([group])
+    hs = []
+    try:
+        for i in range(runs):
+            h = rt.submit(prog, Static(), after=hs[-1:] or None,
+                          epilogue=lambda: prog.swap_buffers(0, 0),
+                          groups=[group])
+            if cbs is not None:
+                h.add_done_callback(lambda _h, i=i: cbs.append(i))
+            hs.append(h)
+            if serial:
+                h.wait(60)
+        for h in hs:
+            assert h.wait(60)
+    finally:
+        rt.shutdown()
+    return hs
+
+
+@pytest.fixture
+def traced():
+    tr = set_tracer(Tracer(capacity=1 << 14, enabled=True))
+    yield tr
+    set_tracer(Tracer(enabled=False))
+
+
+def _spans(tr, name, kernel="qchain"):
+    return sorted((e for e in tr.events() if e[3] == "X" and e[4] == name
+                   and (e[7] or {}).get("kernel", kernel) == kernel),
+                  key=lambda e: e[1])
+
+
+def test_chained_runs_on_one_group_dispatch_ahead(traced):
+    """Runs chained on one group: each after the first is dispatched while
+    its predecessor is still on the (simulated) device, and its service
+    time starts when the predecessor completes, not at its dispatch."""
+    g = DeviceGroup("q", sim_time_per_wi=QT / QN)
+    hs = qchain(qprog(), g, 4)
+    assert [h.ahead for h in hs] == [False, True, True, True]
+    dispatch = _spans(traced, "dispatch")
+    assert len(dispatch) == 4
+    for prev, h, d in zip(hs, hs[1:], dispatch[1:]):
+        assert d[1] < prev.introspector.t_run_end
+        t_free = prev.introspector.records[-1].t_end
+        assert h.introspector.t_run_start == max(d[1], t_free)
+    assert not _spans(traced, "dep_wait")
+
+
+def test_chain_ahead_equals_serial_chain():
+    ahead = qprog()
+    serial = qprog()
+    hs = qchain(ahead, DeviceGroup("a", sim_time_per_wi=QT / QN / 4), 5)
+    hs2 = qchain(serial, DeviceGroup("s"), 5, serial=True)
+    assert any(h.ahead for h in hs) and not any(h.ahead for h in hs2)
+    for h in hs + hs2:
+        h.result()
+    np.testing.assert_array_equal(ahead._ins[0], serial._ins[0])
+    x = np.arange(QN, dtype=np.float32)
+    for _ in range(5):
+        x = 2.0 * x + 1.0
+    np.testing.assert_array_equal(ahead._ins[0], x)
+    # Each run's host output is the one it produced, in its own buffer.
+    for h, h2 in zip(hs, hs2):
+        np.testing.assert_array_equal(h.outputs[0], h2.outputs[0])
+
+
+def test_failure_found_at_completion_poisons_the_run_dispatched_ahead():
+    """Run N fails only when it completes; run N+1, already dispatched
+    behind it, is poisoned and writes nothing back."""
+    calls = []
+
+    def cost(off, size):
+        calls.append(off)
+        if len(calls) == 1:
+            raise RuntimeError("device fault")
+        return size
+
+    prog = qprog(cost)
+    out1 = prog._ins[0]  # the buffer run 2 writes, once the swap ran
+    hs = qchain(prog, DeviceGroup("f", sim_time_per_wi=QT / QN), 2)
+    assert hs[1].ahead
+    with pytest.raises(RunError, match="device fault"):
+        hs[0].result()
+    with pytest.raises(RunError, match="poisoned"):
+        hs[1].result()
+    assert hs[1].outputs[0] is out1
+    np.testing.assert_array_equal(out1, np.arange(QN, dtype=np.float32))
+    assert len(calls) == 1
+
+
+def test_split_run_waits_for_the_group_queue(traced):
+    """A run split over two groups that chains on a run queued on one of
+    them is dispatched only after that run completed."""
+    a = DeviceGroup("sa", sim_time_per_wi=QT / QN)
+    b = DeviceGroup("sb")
+    prog = qprog()
+    rt = Runtime([a, b])
+    try:
+        h1 = rt.submit(prog, Static(), groups=[a],
+                       epilogue=lambda: prog.swap_buffers(0, 0))
+        h2 = rt.submit(prog, Static(), after=[h1], groups=[a, b])
+        h2.result(60)
+    finally:
+        rt.shutdown()
+    assert not h2.ahead
+    first = min(e[1] for e in _spans(traced, "dispatch")[1:])
+    assert first >= h1.introspector.t_run_end
+    np.testing.assert_array_equal(prog._outs[0],
+                                  4.0 * np.arange(QN, dtype=np.float32) + 3.0)
+
+
+def test_host_buffer_invalidated_after_its_producer_waits(traced):
+    """A run whose input its predecessor writes, but which the host
+    invalidated meanwhile, reads it from host only after the predecessor
+    completed and wrote it there."""
+    g = DeviceGroup("inv", sim_time_per_wi=QT / QN)
+    prog = qprog()
+    swapped = threading.Event()
+    rt = Runtime([g])
+    try:
+        h1 = rt.submit(prog, Static(), groups=[g], epilogue=lambda: (
+            prog.swap_buffers(0, 0), swapped.set()))
+        assert swapped.wait(60) and not h1.done()
+        prog.invalidate(prog._ins[0])
+        h2 = rt.submit(prog, Static(), after=[h1], groups=[g])
+        h2.result(60)
+    finally:
+        rt.shutdown()
+    assert not h2.ahead
+    assert _spans(traced, "dep_wait")
+    np.testing.assert_array_equal(prog._outs[0],
+                                  4.0 * np.arange(QN, dtype=np.float32) + 3.0)
+
+
+def test_callbacks_fire_in_submit_order():
+    """Chained and independent runs on one group complete, and fire their
+    callbacks, in the order they were submitted."""
+    cbs = []
+    qchain(qprog(), DeviceGroup("cb", sim_time_per_wi=QT / QN / 4), 5,
+           cbs=cbs)
+    g = DeviceGroup("cb2", sim_time_per_wi=QT / QN / 4)
+    rt = Runtime([g])
+    seen = []
+    try:
+        hs = [rt.submit(qprog(), Static(), groups=[g]) for _ in range(5)]
+        for i, h in enumerate(hs):
+            h.add_done_callback(lambda _h, i=i: seen.append(i))
+        for h in hs:
+            h.result(60)
+    finally:
+        rt.shutdown()
+    assert cbs == list(range(5)) and seen == list(range(5))
+
+
+def test_many_group_queues_under_thread_switching_stress():
+    """More group queues than cores, each with a chain of runs and an
+    independent run interleaved, under a short switch interval: every chain
+    ends at the serial chain's value and every group completes in submit
+    order."""
+    import os
+    import sys
+
+    n_groups, runs = (os.cpu_count() or 4) + 4, 12
+    groups = [DeviceGroup(f"stress{i}") for i in range(n_groups)]
+    progs = [qprog() for _ in groups]
+    order = {g.name: [] for g in groups}
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    rt = Runtime(groups)
+    try:
+        hs = []
+        for k in range(runs):
+            for g, p in zip(groups, progs):
+                prev = [h for h in hs if h.targets == [g] and h.program is p]
+                h = rt.submit(p, Static(), after=prev[-1:] or None, groups=[g],
+                              epilogue=lambda p=p: p.swap_buffers(0, 0))
+                h.add_done_callback(
+                    lambda _h, n=g.name, k=k: order[n].append(2 * k))
+                other = rt.submit(qprog(), Static(), groups=[g])
+                other.add_done_callback(
+                    lambda _h, n=g.name, k=k: order[n].append(2 * k + 1))
+                hs += [h, other]
+        for h in hs:
+            assert h.wait(120)
+            h.result()
+    finally:
+        rt.shutdown()
+        sys.setswitchinterval(old)
+    x = np.arange(QN, dtype=np.float32)
+    for _ in range(runs):
+        x = 2.0 * x + 1.0
+    for p in progs:
+        np.testing.assert_array_equal(p._ins[0], x)
+    for g in groups:
+        assert order[g.name] == list(range(2 * runs))

@@ -416,3 +416,99 @@ def test_serving_programs_take_weights_as_arguments(model, kind):
     assert main.count("%arg") >= len(weights)
     smallest_matrix = min(w.size for w in weights if w.ndim >= 2)
     assert max(_constant_sizes(hlo)) < smallest_matrix
+
+
+# ----------------------------------------------------- one segment ahead
+def _traced_spans(run):
+    from repro.core.trace import Tracer, set_tracer
+
+    tr = set_tracer(Tracer(capacity=1 << 16, enabled=True))
+    try:
+        run()
+        return [e for e in tr.events() if e[3] == "X"]
+    finally:
+        set_tracer(Tracer(enabled=False))
+
+
+def test_segment_ahead_tokens_unchanged(model, reference):
+    """With a segment queued ahead of the one running: a request that ends
+    at ``max_new_cap`` while another still decodes (the segment queued for
+    that one decodes its slot past the cache's last row), one that exits in
+    the middle of a segment, and joins that drain the boundary first all
+    leave every stream bit-identical."""
+    cfg, api, params = model
+    g = DeviceGroup("ahead")
+    cap, out = 24, {}
+
+    def run():
+        with InferenceServer(cfg, api, params, groups=[g],
+                             scheduler=Static(), buckets=(PLEN,),
+                             max_batch=4, seg_len=2, max_new_cap=cap,
+                             max_wait_ms=1.0) as srv:
+            ps = prompts_for(cfg, 81, 3)
+            hs = [srv.submit(ps[0], cap)]
+            deadline = time.monotonic() + 60
+            while srv.stats()["segments"] < 1:
+                assert time.monotonic() < deadline
+                time.sleep(0.002)
+            hs += [srv.submit(ps[1], cap), srv.submit(ps[2], 4)]
+            out["res"] = [(p, h.max_new_tokens, h.result(timeout=300))
+                          for p, h in zip(ps, hs)]
+            out["hs"], out["stats"] = hs, srv.stats()
+
+    spans = _traced_spans(run)
+    for p, n, toks in out["res"]:
+        np.testing.assert_array_equal(toks, reference(p, n))
+    a, b, _ = out["hs"]
+    assert b.t_first_token < a.t_done  # b decoded past a's last segment
+    s = out["stats"]
+    assert s["midstream_joins"] >= 1, s
+    segs = [e for e in spans if e[4] == "segment"]
+    assert len(segs) == s["segments"]
+    assert any(e[7]["ahead"] == 1 for e in segs)
+    assert any(e[4] == "drain" for e in spans)
+    # A segment ahead is dispatched while the one before it runs.
+    starts = sorted(e[1] for e in spans if e[4] == "dispatch"
+                    and e[7]["kernel"].startswith("decode_seg"))
+    ends = sorted(e[2] for e in segs)
+    assert any(d < end for d, end in zip(starts[1:], ends))
+
+
+@pytest.mark.parametrize("mode", ["spec", "chunked", "paged"])
+def test_spec_chunked_paged_run_one_segment_at_a_time(model, reference,
+                                                      mode):
+    """Speculative, chunked and paged groups keep one segment in flight:
+    each is submitted after the one before it was harvested."""
+    cfg, api, params = model
+    kw = {"spec": {"draft": DraftSpec(cfg, params, k=2)},
+          "chunked": {"chunk_len": 4},
+          "paged": {"paged": PagedSpec(block_len=4)}}[mode]
+    out = {}
+
+    def run():
+        with InferenceServer(cfg, api, params,
+                             groups=[DeviceGroup(f"one-{mode}")],
+                             scheduler=Static(), buckets=(PLEN,),
+                             max_batch=2, seg_len=2, max_new_cap=8,
+                             max_wait_ms=1.0, **kw) as srv:
+            ps = prompts_for(cfg, 83, 3)
+            hs = [srv.submit(p, 7) for p in ps]
+            deadline = time.monotonic() + 60
+            while not srv._groups:
+                assert time.monotonic() < deadline
+                time.sleep(0.001)
+            out["ahead"] = next(iter(srv._groups.values())).ahead
+            out["res"] = [(p, h.result(timeout=300)) for p, h in zip(ps, hs)]
+
+    spans = _traced_spans(run)
+    for p, toks in out["res"]:
+        np.testing.assert_array_equal(toks, reference(p, 7))
+    assert not out["ahead"]
+    segs = sorted((e for e in spans if e[4] == "segment"), key=lambda e: e[1])
+    waits = sorted((e for e in spans if e[4] == "queue_wait"
+                    and "seg" in e[7]["kernel"]), key=lambda e: e[1])
+    assert len(segs) == len(waits) >= 2
+    assert all(e[7]["ahead"] == 0 for e in segs)
+    assert not any(e[4] == "drain" for e in spans)
+    for prev, w in zip(segs, waits[1:]):
+        assert w[1] >= prev[2]
